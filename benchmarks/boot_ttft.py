@@ -10,7 +10,9 @@ into a persistent cache keyed on the artifact fingerprint, so a warm
 boot deserializes executables instead of compiling them.
 
 Three cells, each a FRESH subprocess (an honest boot — no XLA state,
-no in-process jit caches, JAX's own compilation cache disabled):
+no in-process jit caches, JAX's own compilation cache turned off). The
+artifact is built in a child process too: the parent never imports JAX,
+so on a TPU host every child can take the chip.
 
 * ``traced``   — historical lazy-jit boot; TTFT pays the traces.
 * ``aot_cold`` — AOT boot with an empty cache; pays the same compiles
@@ -49,11 +51,16 @@ SMOKE_GRID = {"slots": 2, "max_len": 64, "prompt_len": 8, "n_new": 8}
 
 
 def ensure_artifact(path: str = ARTIFACT) -> str:
-    """Build (once) the llama-mini drank artifact the boot cells serve.
-    Reuse is deliberate: the bench's claim is about boot mechanics, and
-    all three cells share whatever artifact sits here."""
-    if os.path.exists(os.path.join(path, "compressed", "manifest.json")):
-        return path
+    """Build (once, in a child process) the llama-mini drank artifact the
+    boot cells serve. Reuse is deliberate: the bench's claim is about boot
+    mechanics, and all three cells share whatever artifact sits here."""
+    if not os.path.exists(os.path.join(path, "compressed", "manifest.json")):
+        print(_spawn(["--build-artifact", path], _child_env()).stdout,
+              end="", flush=True)
+    return path
+
+
+def build_artifact(path: str) -> None:
     import jax
 
     from benchmarks.common import calib_batches
@@ -70,7 +77,6 @@ def ensure_artifact(path: str = ARTIFACT) -> str:
     CC.save_plan(path, comp, plan, cfg)
     print(f"  built boot artifact at {path} "
           f"({plan.summary['achieved_ratio']:.1%} removed)", flush=True)
-    return path
 
 
 # ---------------------------------------------------------------------------
@@ -109,23 +115,33 @@ def run_cell(cell: str, artifact: str, grid: dict) -> None:
         "stats": {k: cb.stats.get(k, 0) for k in keys}}), flush=True)
 
 
-def _spawn_cell(cell: str, artifact: str, grid: dict) -> dict:
+def _child_env() -> dict:
     env = dict(os.environ)
-    # JAX's own persistent compilation cache would silently warm the
-    # "cold" cells; the only cache under test is serve/aot.py's
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    env["REPRO_AOT_CACHE"] = AOT_CACHE
     env["PYTHONPATH"] = (os.path.join(ROOT, "src") + os.pathsep + ROOT
                          + os.pathsep + env.get("PYTHONPATH", ""))
-    t0 = time.perf_counter()
+    return env
+
+
+def _spawn(args: list, env: dict) -> subprocess.CompletedProcess:
     proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.boot_ttft", "--cell", cell,
-         "--artifact", artifact, "--grid", json.dumps(grid)],
+        [sys.executable, "-m", "benchmarks.boot_ttft", *args],
         capture_output=True, text=True, env=env, cwd=ROOT, timeout=1800)
-    wall = time.perf_counter() - t0
     if proc.returncode != 0:
-        raise RuntimeError(f"boot cell {cell} failed:\n{proc.stdout}\n"
-                           f"{proc.stderr}")
+        raise RuntimeError(f"boot_ttft child {args} failed:\n{proc.stdout}"
+                           f"\n{proc.stderr}")
+    return proc
+
+
+def _spawn_cell(cell: str, artifact: str, grid: dict) -> dict:
+    env = _child_env()
+    # JAX's own persistent compilation cache would silently warm the
+    # "cold" cells; the only cache under test is serve/aot.py's
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+    env["REPRO_AOT_CACHE"] = AOT_CACHE
+    t0 = time.perf_counter()
+    proc = _spawn(["--cell", cell, "--artifact", artifact,
+                   "--grid", json.dumps(grid)], env)
+    wall = time.perf_counter() - t0
     line = [ln for ln in proc.stdout.splitlines() if ln.startswith(MARK)]
     assert line, f"no {MARK!r} line from cell {cell}:\n{proc.stdout}"
     out = json.loads(line[-1][len(MARK):])
@@ -195,7 +211,12 @@ def main(argv=None):
                     help=argparse.SUPPRESS)   # internal: child mode
     ap.add_argument("--artifact", default="", help=argparse.SUPPRESS)
     ap.add_argument("--grid", default="", help=argparse.SUPPRESS)
+    ap.add_argument("--build-artifact", default="",
+                    help=argparse.SUPPRESS)   # internal: child mode
     args = ap.parse_args(argv)
+    if args.build_artifact:
+        build_artifact(args.build_artifact)
+        return 0
     if args.cell:
         run_cell(args.cell, args.artifact, json.loads(args.grid))
         return 0

@@ -1,9 +1,13 @@
 """Sharded streaming-calibration capture on a multi-device host mesh
 (the PR-5 tentpole; DESIGN.md §1.6).
 
-Forces ``--xla_force_host_platform_device_count=8`` BEFORE jax initializes
-so the (data=8) mesh paths run with real per-device buffers, and measures
-three capture routes per grid cell:
+This is a CPU rehearsal of the mesh paths: it sets ``JAX_PLATFORMS=cpu``
+(unless the caller set a platform) and
+``--xla_force_host_platform_device_count=8`` BEFORE jax initializes,
+so the (data=8) mesh runs on eight virtual CPU devices with real
+per-device buffers. Its timings are CPU numbers. The chip version of the
+mesh-capture parity check is ``python chip_smoke.py --chips 4``. It
+measures three capture routes per grid cell:
 
   mesh-replicated   per-shard partial Grams psum'd into replicated (D,D)
                     accumulators (the PR-2 layout, now pipelined)

@@ -1,21 +1,17 @@
 """Shared benchmark plumbing: trained-model loading, data, PPL eval,
 result caching (every bench caches to experiments/results/<name>.json so
-the aggregate runner is resumable on this 1-core container)."""
+the aggregate runner is resumable).
+
+JAX is imported inside the helpers that need it, never at module level: a
+bench whose parent process only spawns children (``boot_ttft``) must not
+touch JAX, because on a TPU host the first process to do so holds the
+chip and its children could not get it."""
 from __future__ import annotations
 
 import json
 import os
 import time
 from typing import Dict, List, Optional
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.ckpt import store
-from repro.configs import get_config
-from repro.data.synthetic import DataConfig, SyntheticLM, calibration_batches
-from repro.train import step as TS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RESULTS = os.path.join(ROOT, "experiments", "results")
@@ -42,7 +38,8 @@ def cached(name: str, fn, force: bool = False):
     return out
 
 
-def data_config(cfg, seq_len: int = 128, seed: int = 0) -> DataConfig:
+def data_config(cfg, seq_len: int = 128, seed: int = 0):
+    from repro.data.synthetic import DataConfig
     return DataConfig(vocab_size=cfg.vocab_size, seq_len=seq_len,
                       global_batch=8, seed=seed)
 
@@ -50,6 +47,11 @@ def data_config(cfg, seq_len: int = 128, seed: int = 0) -> DataConfig:
 def load_trained(arch: str = "llama-mini", run: str = "mini_mha",
                  overrides: Optional[Dict] = None):
     """Load the latest checkpoint of a background training run."""
+    import jax
+
+    from repro.ckpt import store
+    from repro.configs import get_config
+    from repro.train import step as TS
     cfg = get_config(arch)
     if overrides:
         cfg = cfg.replace(**overrides)
@@ -61,6 +63,10 @@ def load_trained(arch: str = "llama-mini", run: str = "mini_mha",
 
 def eval_batches(cfg, n_batches: int = 4, batch: int = 8,
                  seq_len: int = 128, seed: int = 0) -> List[Dict]:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.data.synthetic import SyntheticLM
     lm = SyntheticLM(data_config(cfg, seq_len, seed))
     out = []
     for i in range(n_batches):
@@ -72,12 +78,16 @@ def eval_batches(cfg, n_batches: int = 4, batch: int = 8,
 
 def calib_batches(cfg, n_samples: int = 16, batch: int = 8,
                   seq_len: int = 128, seed: int = 0) -> List[Dict]:
+    import jax.numpy as jnp
+
+    from repro.data.synthetic import calibration_batches
     dcfg = data_config(cfg, seq_len, seed)
     return [{"tokens": jnp.asarray(b["tokens"])}
             for b in calibration_batches(dcfg, n_samples, batch)]
 
 
 def ppl_of(params, cfg, batches) -> Dict[str, float]:
+    from repro.train import step as TS
     return TS.evaluate_ppl(params, cfg, batches)
 
 
@@ -87,6 +97,7 @@ def calib_max_rel_err(col, oracle) -> float:
     as streaming-whitening factors compare through RᵀR (the Gram the
     factor represents) — shared by the capture benches so the CI parity
     bar stays uniform across the single-device and mesh paths."""
+    import numpy as np
     worst = 0.0
     for tag in oracle.gram:
         got = (col.gram[tag] if tag in col.gram

@@ -13,8 +13,9 @@ export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
 # One persistent XLA compilation cache for every step in this script (and,
 # via the workflow's cache action, across CI runs): each jit program is
-# compiled once, then replayed. The boot-TTFT bench strips this variable
-# from its child cells — its cold/warm boots must stay honest.
+# compiled once, then replayed. The same default root as
+# src/repro/compile_cache.py. The boot-TTFT bench turns JAX's cache off in
+# its boot cells — its cold/warm boots must stay honest.
 export JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.cache/jax}"
 mkdir -p "$JAX_COMPILATION_CACHE_DIR"
 

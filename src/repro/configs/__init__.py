@@ -44,5 +44,12 @@ def get_config(arch_id: str) -> ModelConfig:
     return cfg
 
 
+def register(arch_id: str, cfg: ModelConfig) -> None:
+    """Make ``get_config(arch_id)`` (and so every entry point that takes
+    an arch id) resolve to ``cfg``, e.g. a depth-cut variant of a
+    registered model for a smoke run."""
+    _cache[arch_id.replace("_", "-")] = cfg
+
+
 def all_configs() -> Dict[str, ModelConfig]:
     return {a: get_config(a) for a in ARCH_IDS}

@@ -1,4 +1,4 @@
-"""SmolLM-360M (llama-arch small) [hf:HuggingFaceTB/SmolLM-135M; hf].
+"""SmolLM-360M (llama-arch small) [hf:HuggingFaceTB/SmolLM-360M; hf].
 
 32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152.
 """

@@ -168,6 +168,22 @@ def _tag_whitened(whiten, tag: str) -> bool:
     return whiten is True or (whiten is not None and tag in whiten)
 
 
+def _split_weights(tagged: Params):
+    """(array leaves of ``tagged``, rebuild). The capture steps take the
+    weights as jit ARGUMENTS and rebuild the tagged tree at trace time:
+    closed over, they would be baked into the executable as constants
+    (gigabytes for a full-width model, and a compile to match)."""
+    leaves, treedef = jax.tree.flatten(tagged)
+    is_arr = [hasattr(x, "shape") for x in leaves]
+    weights = [x for x, a in zip(leaves, is_arr) if a]
+
+    def rebuild(ws):
+        it = iter(ws)
+        return treedef.unflatten([next(it) if a else x
+                                  for x, a in zip(leaves, is_arr)])
+    return weights, rebuild
+
+
 def _spec_axes(spec) -> tuple:
     """First-dimension mesh axes of a PartitionSpec, as a flat tuple."""
     entry = spec[0] if len(spec) else None
@@ -227,8 +243,8 @@ class StreamingCalibrator:
 
     With ``mesh``, capture is a two-stage pipeline (DESIGN.md §1.6):
     stage 1 (``_capture``) runs the forward pass per data-parallel shard
-    inside ``shard_map`` (batch rows split over ``data_axes``, params
-    closed over and replicated) and emits per-shard partials with NO
+    inside ``shard_map`` (batch rows split over ``data_axes``, weights
+    replicated) and emits per-shard partials with NO
     collectives; stage 2 (``_fold``) reduces the PREVIOUS batch's
     partials into the donated accumulators. ``ingest`` dispatches stage 1
     of batch k+1 before stage 2 of batch k, so the per-batch
@@ -293,6 +309,7 @@ class StreamingCalibrator:
                  whiten_tags=None, shard_grams_above: int = 4096):
         self.cfg = cfg
         self.tagged = tag_linears(list_params)
+        self._weights, self._rebuild = _split_weights(self.tagged)
         self.mesh = mesh
         self.flush_every = max(1, flush_every)
         self.use_kernel = use_kernel
@@ -359,17 +376,17 @@ class StreamingCalibrator:
         return dict(self._routes)
 
     # -- step construction --------------------------------------------------
-    def _tape_partials(self, batch, raw=None):
+    def _tape_partials(self, weights, batch, raw=None):
         from repro.models import transformer as T
         tape = StreamingTape(self.use_kernel, whiten=self.whiten, raw=raw)
         with tape:
-            T.forward(self.tagged, self.cfg, batch)
+            T.forward(self._rebuild(weights), self.cfg, batch)
         return tape.partials, tape.xblocks
 
     def _build_step(self):
         """Single-device path: one fused jit (forward + fold)."""
-        def step(accs, batch):
-            parts, xblocks = self._tape_partials(batch)
+        def step(accs, weights, batch):
+            parts, xblocks = self._tape_partials(weights, batch)
             new = {}
             for tag, acc in accs.items():
                 p = parts[tag]
@@ -400,8 +417,9 @@ class StreamingCalibrator:
             key = "x" if tag in raw_tags else "gram"
             return {"absx": P(axes), "count": P(axes), key: P(axes)}
 
-        def capture_body(batch):
-            parts, xblocks = self._tape_partials(batch, raw=raw_tags)
+        def capture_body(weights, batch):
+            parts, xblocks = self._tape_partials(weights, batch,
+                                                 raw=raw_tags)
             out = {}
             for tag, p in parts.items():
                 e = {"absx": p["absx"][None], "count": p["count"][None]}
@@ -413,7 +431,7 @@ class StreamingCalibrator:
             return out
 
         capture = jax.jit(shard_map(
-            capture_body, mesh=mesh, in_specs=(P(axes),),
+            capture_body, mesh=mesh, in_specs=(P(), P(axes)),
             out_specs={t: part_spec(t) for t in self._dims}))
 
         def stat_fold(acc, p):
@@ -535,13 +553,17 @@ class StreamingCalibrator:
                 else:
                     self._init_chol(self._accs)
                     self._capture, self._folds = self._build_mesh_steps()
+                    # replicated once, not re-sent with every batch
+                    self._weights = jax.device_put(
+                        self._weights,
+                        jax.sharding.NamedSharding(self.mesh, P()))
             if self.mesh is None:
-                self._accs = self._step(self._accs, batch)
+                self._accs = self._step(self._accs, self._weights, batch)
             else:
                 # dispatch the next capture BEFORE reducing the previous
                 # batch's partials: both are queued asynchronously, so the
                 # fold's collectives overlap the new forward pass
-                parts = self._capture(batch)
+                parts = self._capture(self._weights, batch)
                 self._fold_pending()
                 self._pending = parts
         self._since_flush += 1

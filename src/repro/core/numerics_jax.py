@@ -30,6 +30,12 @@ truncated tail energy is restored exactly via the trace identity
 (``_dec_rsvd``), so rank allocation sees a full-length spectrum with the
 right total energy (DESIGN.md §1.5).
 
+Precision: every public entry point runs under
+``jax.default_matmul_precision("highest")``. The operands are fp32 Grams
+and weights, not bf16 activations; at the TPU's default matmul precision
+they would be rounded to bf16 inside every dot, far below the fp64
+oracle's parity bar. On the CPU fp32 dots are exact fp32 either way.
+
 Structure note: the pipeline is deliberately split into SEVERAL small
 jitted stages instead of one fused jit. XLA:CPU runs the dense dots in a
 computation noticeably slower when the same executable also contains
@@ -46,6 +52,16 @@ import jax
 import jax.numpy as jnp
 
 MAX_DAMP_TRIES = 12          # matches numerics.cholesky_whitener
+
+
+def _highest(fn):
+    """Run ``fn`` (and trace every jitted stage it calls) at full fp32
+    matmul precision."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -108,6 +124,7 @@ def _fix_factor(R: jax.Array) -> jax.Array:
         * jnp.eye(d, dtype=jnp.float32)
 
 
+@_highest
 @jax.jit
 def combine_factors(Rs: jax.Array) -> jax.Array:
     """Merge per-member streaming-whitening factors into one group factor:
@@ -118,6 +135,7 @@ def combine_factors(Rs: jax.Array) -> jax.Array:
     return jnp.linalg.qr(stacked, mode="r")
 
 
+@_highest
 @jax.jit
 def tree_reduce_factors(Rs: jax.Array) -> jax.Array:
     """Exact distributed-whitening reduction (DESIGN.md §1.6): merge
@@ -320,6 +338,7 @@ def _dec_rsvd(W, L, sL, k, oversample, iters, seed):
     return sig, B, C
 
 
+@_highest
 def decompose(W: jax.Array, *, gram: Optional[jax.Array] = None,
               factor: Optional[jax.Array] = None,
               diag: Optional[jax.Array] = None,
@@ -374,6 +393,7 @@ def _refine_normal_eqs(L2, B, eps):
     return F, BtGB
 
 
+@_highest
 def refine_solve(B: jax.Array, G: Optional[jax.Array], W: jax.Array,
                  eps: float = 1e-8,
                  factor: Optional[jax.Array] = None) -> jax.Array:

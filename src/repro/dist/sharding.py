@@ -15,11 +15,7 @@ import threading
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
-
-try:                                    # jax >= 0.6 re-exports at top level
-    from jax import shard_map
-except ImportError:                     # 0.4.x: experimental only
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax import shard_map  # noqa: F401  (re-exported for core.capture)
 
 P = jax.sharding.PartitionSpec
 

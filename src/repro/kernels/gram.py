@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(nn: int, xi_ref, xj_ref, g_ref, acc_ref):
     n = pl.program_id(2)
@@ -49,9 +47,12 @@ def gram_blocked(x: jax.Array, *, bi: int = 256, bj: int = 256,
             pl.BlockSpec((bn, bj), lambda i, j, n: (n, j)),
         ],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j, n: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((D, D), jnp.float32),
+        # inside shard_map (the mesh capture) the Gram varies over the
+        # same mesh axes as its input rows
+        out_shape=jax.ShapeDtypeStruct((D, D), jnp.float32,
+                                       vma=jax.typeof(x).vma),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, x)

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _kernel(nk: int, x_ref, b_ref, c_ref, y_ref, t_ref):
     s = pl.program_id(1)
@@ -107,7 +105,7 @@ def lowrank_gemv(x: jax.Array, B: jax.Array, C: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((M, R), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(x, B, C)
 
@@ -138,6 +136,6 @@ def lowrank_matmul_2d(x: jax.Array, B: jax.Array, C: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, R), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(x, B, C)

@@ -372,8 +372,6 @@ def lower_cell(arch: str, shape_name: str, mesh, *, compressed: float = 0.0,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax < 0.5: one dict per device
-        cost = cost[0] if cost else {}
     hlo_txt = compiled.as_text()
     if hlo_out:
         import zstandard

@@ -141,7 +141,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "warm cache boots without any XLA compiles")
     ap.add_argument("--aot-cache-dir", default="",
                     help="compilation cache location (default "
-                         "$REPRO_AOT_CACHE or ~/.cache/repro/aot)")
+                         "$REPRO_AOT_CACHE, else repro-aot under "
+                         "$JAX_COMPILATION_CACHE_DIR or .cache/jax)")
     ap.add_argument("--replicas", type=int, default=1,
                     help="run N engine replicas behind one router that "
                          "places requests on the least-loaded replica "
@@ -196,9 +197,11 @@ def parse_serve_options(argv=None):
 
 
 def main(argv=None) -> int:
+    from repro import compile_cache
     from repro.serve.api import serve
 
     opts = parse_serve_options(argv)
+    compile_cache.enable()
     res = serve(opts, echo=print)
     print(json.dumps(res.report, indent=1))
     if res.status != "drained":

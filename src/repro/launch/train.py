@@ -17,7 +17,6 @@ import os
 import sys
 
 
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -42,12 +41,14 @@ def main(argv=None) -> int:
     ap.add_argument("--history-out", default="")
     args = ap.parse_args(argv)
 
+    from repro import compile_cache
     from repro.configs import get_config
     from repro.data.synthetic import DataConfig
     from repro.optim.adamw import OptimizerConfig
     from repro.train import step as TS
     from repro.train.loop import LoopConfig, Trainer
 
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

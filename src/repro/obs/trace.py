@@ -257,25 +257,17 @@ def device_trace(logdir: Optional[str]):
     """Optional device-level capture: wraps ``jax.profiler``
     start/stop_trace around the block when ``logdir`` is set (XLA/TPU
     timelines land there, viewable in TensorBoard or Perfetto); a no-op
-    when ``logdir`` is falsy or the profiler is unavailable."""
+    when ``logdir`` is falsy. A profiler that cannot start or stop raises:
+    a capture that was asked for never silently goes missing."""
     if not logdir:
         yield None
         return
+    import jax
+    jax.profiler.start_trace(logdir)
     try:
-        import jax
-        jax.profiler.start_trace(logdir)
-        started = True
-    except Exception:           # headless jaxlib without profiler support
-        started = False
-    try:
-        yield logdir if started else None
+        yield logdir
     finally:
-        if started:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        jax.profiler.stop_trace()
 
 
 # ---------------------------------------------------------------------------

@@ -45,7 +45,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import jaxlib
 
+from repro import compile_cache
 from repro.config import ModelConfig
 from repro.obs import trace
 
@@ -65,14 +67,14 @@ ROLE_PURGE_PAGED = "purge_paged"
 ROLE_COPY_BLOCKS = "copy_blocks"
 
 AOT_STAT_KEYS = ("aot_compiles", "aot_cache_hits", "aot_deser_failures",
-                 "aot_fallbacks")
+                 "aot_fallbacks", "aot_store_failures")
 
 
 def default_cache_dir() -> str:
-    """Resolution order: ``$REPRO_AOT_CACHE`` then ``~/.cache/repro/aot``."""
-    return os.environ.get(
-        "REPRO_AOT_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro", "aot"))
+    """Resolution order: ``$REPRO_AOT_CACHE``, then ``repro-aot`` under
+    the compile-cache root (``repro.compile_cache.cache_root``)."""
+    return os.environ.get("REPRO_AOT_CACHE") or os.path.join(
+        compile_cache.cache_root(), "repro-aot")
 
 
 # ---------------------------------------------------------------------------
@@ -207,7 +209,7 @@ def cache_key(fingerprint: str, role: str, variant: Tuple, sig: str,
                   "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
                   "dtype": str(cfg.dtype)},
         "jax": jax.__version__,
-        "jaxlib": getattr(jax, "jaxlib_version", ""),
+        "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
     }
     return hashlib.sha256(
@@ -247,16 +249,20 @@ class AotCache:
     def has(self, key: str) -> bool:
         return os.path.exists(self.path(key))
 
-    def store(self, key: str, compiled) -> None:
+    def store(self, key: str, compiled) -> bool:
+        """Persist ``compiled`` under ``key``. Returns False when the
+        backend cannot serialize it (nothing is written; the registry
+        counts it as ``aot_store_failures``)."""
         from jax.experimental.serialize_executable import serialize
         try:
             blob = pickle.dumps(serialize(compiled))
         except Exception:
-            return                # unserializable backend: cache disabled
+            return False
         fd, tmp = tempfile.mkstemp(dir=self.dir, suffix=".tmp")
         with os.fdopen(fd, "wb") as f:
             f.write(blob)
         os.replace(tmp, self.path(key))
+        return True
 
     def keys(self) -> List[str]:
         return sorted(f[:-5] for f in os.listdir(self.dir)
@@ -382,11 +388,17 @@ class AotRegistry:
 
     def __init__(self, cfg: ModelConfig, scfg, fingerprint: str,
                  cache_dir: Optional[str] = None,
-                 stats: Optional[Dict] = None):
+                 stats: Optional[Dict] = None,
+                 device: Optional[jax.Device] = None):
         from repro.models import transformer as T
         self._T = T
         self.cfg, self.scfg = cfg, scfg
         self.fingerprint = fingerprint
+        # executables are compiled for one device: a replica's own, or the
+        # default device when None
+        self.device = device
+        self._sharding = (jax.sharding.SingleDeviceSharding(device)
+                          if device is not None else None)
         self.cache = AotCache(cache_dir or default_cache_dir())
         self.stats = stats if stats is not None else {}
         for k in AOT_STAT_KEYS:
@@ -429,6 +441,25 @@ class AotRegistry:
         raise KeyError(role)
 
     # ---- resolution ------------------------------------------------------
+    def _key(self, role: str, variant: Tuple, args: Tuple) -> str:
+        sig = _sig_of(args)
+        if self.device is not None:
+            sig += f";device={self.device.id}"
+        return cache_key(self.fingerprint, role, variant, sig, self.scfg,
+                         self.cfg)
+
+    def _compile(self, role: str, args: Tuple):
+        """Lower and compile ``role`` for ``args``; abstract avals carry
+        no device of their own, so they are placed on this registry's."""
+        fn, donate = self._role_fn(role)
+        if self._sharding is not None:
+            args = jax.tree.map(
+                lambda a: (jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                sharding=self._sharding)
+                           if isinstance(a, jax.ShapeDtypeStruct) else a),
+                args)
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
     def _resolve(self, role: str, variant: Tuple, args: Tuple):
         """(role, variant) → compiled executable, via memo → disk →
         compile. ``args`` may mix concrete arrays and ShapeDtypeStructs —
@@ -437,9 +468,7 @@ class AotRegistry:
         exe = self._mem.get(memk)
         if exe is not None:
             return exe
-        fn, donate = self._role_fn(role)
-        key = cache_key(self.fingerprint, role, variant, _sig_of(args),
-                        self.scfg, self.cfg)
+        key = self._key(role, variant, args)
         with trace.span("aot_deserialize", role=role,
                         variant=list(variant)):
             exe = self.cache.load(key)
@@ -449,10 +478,10 @@ class AotRegistry:
         if exe is None:
             with trace.span("aot_compile", role=role,
                             variant=list(variant)):
-                compiled = jax.jit(fn, donate_argnums=donate
-                                   ).lower(*args).compile()
+                compiled = self._compile(role, args)
             self.stats["aot_compiles"] += 1
-            self.cache.store(key, compiled)
+            if not self.cache.store(key, compiled):
+                self.stats["aot_store_failures"] += 1
             exe = compiled
         else:
             self.stats["aot_cache_hits"] += 1
@@ -468,9 +497,7 @@ class AotRegistry:
             # control): recompile against the live arguments and swap the
             # entry — degraded to a compile, never to a wrong answer
             self.stats["aot_fallbacks"] += 1
-            fn, donate = self._role_fn(role)
-            compiled = jax.jit(fn, donate_argnums=donate
-                               ).lower(*args).compile()
+            compiled = self._compile(role, args)
             self.stats["aot_compiles"] += 1
             self._mem[(role, variant)] = compiled
             return compiled(*args)
@@ -543,9 +570,7 @@ class AotRegistry:
         persists) now, which is the whole cold-boot cost."""
         if (role, variant) in self._mem:
             return
-        key = cache_key(self.fingerprint, role, variant, _sig_of(args),
-                        self.scfg, self.cfg)
-        if self.cache.has(key):
+        if self.cache.has(self._key(role, variant, args)):
             return                 # servable; lazy-deserialized on use
         self._resolve(role, variant, args)
 

@@ -117,7 +117,7 @@ class ServeOptions:
     stats_json: str = ""
     # --- front door -------------------------------------------------------
     aot: bool = False               # AOT-compiled executables + disk cache
-    aot_cache_dir: str = ""         # "" = $REPRO_AOT_CACHE or ~/.cache
+    aot_cache_dir: str = ""         # "" = aot.default_cache_dir()
     replicas: int = 1               # N engines behind one Router
     stream: bool = False            # drive through FrontDoor even for N=1
     # --- observability (DESIGN.md §6) -------------------------------------
@@ -257,11 +257,23 @@ def _compress_in_process(opts: ServeOptions, params, cfg, echo=None):
     return params, plan
 
 
-def _registry_for(opts: ServeOptions, cfg, scfg, fingerprint: str):
+def _registry_for(opts: ServeOptions, cfg, scfg, fingerprint: str,
+                  device=None):
     if not opts.aot:
         return None                       # engine defaults to traced
     return AotRegistry(cfg, scfg, fingerprint,
-                       cache_dir=opts.aot_cache_dir or None)
+                       cache_dir=opts.aot_cache_dir or None, device=device)
+
+
+def _replica_device(opts: ServeOptions, replica: int):
+    """With several replicas, replica i lives on local device i (round
+    robin when there are fewer devices than replicas); a single replica
+    stays on the default device."""
+    if opts.replicas == 1:
+        return None
+    import jax
+    devices = jax.local_devices()
+    return devices[replica % len(devices)]
 
 
 def load_engine(opts: ServeOptions, *, replica: int = 0,
@@ -280,16 +292,17 @@ def load_engine(opts: ServeOptions, *, replica: int = 0,
     cfg = get_config(opts.arch)
     scfg = opts.serve_config()
     resil = _resilience_kwargs(opts, replica=replica, echo=echo)
+    device = _replica_device(opts, replica)
 
     if opts.compressed_ckpt:
         from repro.ckpt.store import artifact_fingerprint
         from repro.core.compress import ARTIFACT_NAME
         fp = artifact_fingerprint(opts.compressed_ckpt, name=ARTIFACT_NAME)
-        reg = _registry_for(opts, cfg, scfg, fp)
+        reg = _registry_for(opts, cfg, scfg, fp, device)
         cb = from_compressed(opts.compressed_ckpt, cfg, scfg,
                              verify=opts.verify,
                              load_retries=opts.load_retries,
-                             executables=reg, **resil)
+                             executables=reg, device=device, **resil)
         _echo(echo, f"booted from compressed checkpoint "
                     f"{opts.compressed_ckpt} "
                     f"({cb.plan.summary['achieved_ratio']:.1%} removed, "
@@ -314,8 +327,9 @@ def load_engine(opts: ServeOptions, *, replica: int = 0,
             params, plan = _compress_in_process(opts, params, cfg,
                                                 echo=echo)
         reg = _registry_for(opts, cfg, scfg,
-                            aotlib.live_fingerprint(params, cfg))
-        cb = ContinuousBatcher(params, cfg, scfg, executables=reg, **resil)
+                            aotlib.live_fingerprint(params, cfg), device)
+        cb = ContinuousBatcher(params, cfg, scfg, executables=reg,
+                               device=device, **resil)
         cb.plan = plan
     if opts.aot:
         t0 = time.perf_counter()

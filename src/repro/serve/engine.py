@@ -304,6 +304,18 @@ class Engine:
                 "ms_per_step": dt / n_new * 1000.0}
 
 
+def _put_shared(tree, device: jax.Device):
+    """``jax.device_put`` leaf by leaf, keeping aliased leaves (a group's
+    shared basis B) aliased on the device instead of copying each use."""
+    memo: Dict[int, jax.Array] = {}
+
+    def one(a):
+        if id(a) not in memo:
+            memo[id(a)] = jax.device_put(a, device)
+        return memo[id(a)]
+    return jax.tree.map(one, tree)
+
+
 def _bucket_len(n: int, max_len: int) -> int:
     """Next power of two ≥ n (floor 2), capped at max_len. Bucketing prompt
     pads means `_prefill1` compiles once per bucket — at most
@@ -355,7 +367,13 @@ class ContinuousBatcher:
     def __init__(self, params: Params, cfg: ModelConfig, scfg: ServeConfig,
                  admission: Optional[adm.AdmissionConfig] = None,
                  faults=None, heartbeat=None, executables=None,
-                 flight: Optional[frec.FlightRecorder] = None):
+                 flight: Optional[frec.FlightRecorder] = None,
+                 device: Optional[jax.Device] = None):
+        # ``device`` pins the weights and the KV pool to one local device
+        # (a replica per chip); every other input is uncommitted and
+        # follows them there
+        if device is not None:
+            params = _put_shared(params, device)
         self.params, self.cfg, self.scfg = params, cfg, scfg
         self.plan = None
         self.acfg = admission or adm.AdmissionConfig()
@@ -395,6 +413,8 @@ class ContinuousBatcher:
             self._req_blocks: Dict[int, tuple] = {}  # rid -> (held, nshared)
         else:
             self.cache = T.init_cache(cfg, scfg.batch, scfg.max_len)
+        if device is not None:
+            self.cache = jax.device_put(self.cache, device)
         self.slots: List[Optional[Request]] = [None] * scfg.batch
         self.tokens = jnp.zeros((scfg.batch, 1), dtype=jnp.int32)
         self.done: List[Request] = []

@@ -4,6 +4,9 @@ stream. Asserts the boot contract (second boot performs zero compiles),
 fingerprint isolation (a different artifact never replays a cached
 executable), and the corruption fallback ladder.
 """
+import dataclasses
+import os
+
 import numpy as np
 import pytest
 
@@ -111,3 +114,71 @@ def test_cache_key_separates_roles_variants_and_config(comp):
     assert k != aotlib.cache_key("sha256:other", "decode", (0,), sig,
                                  SCFG, CFG)
     assert k == aotlib.cache_key(fp, "decode", (0,), sig, SCFG, CFG)
+
+
+def test_unserializable_executable_is_counted_not_silent(tmp_path, comp,
+                                                         monkeypatch):
+    import jax.experimental.serialize_executable as se
+
+    def refuse(compiled):
+        raise RuntimeError("backend cannot serialize")
+    monkeypatch.setattr(se, "serialize", refuse)
+    out, s = _drain(comp, _registry(comp, tmp_path))
+    assert s["aot_compiles"] > 0
+    assert s["aot_store_failures"] == s["aot_compiles"]
+    assert aotlib.AotCache(str(tmp_path)).keys() == []
+    oracle, _ = _drain(comp)
+    assert out == oracle
+
+
+def test_cache_key_tracks_the_installed_jaxlib(comp, monkeypatch):
+    import jaxlib
+    fp = aotlib.live_fingerprint(comp, CFG)
+    k = aotlib.cache_key(fp, "decode", (0,), "sig", SCFG, CFG)
+    monkeypatch.setattr(jaxlib, "__version__", "0.0.0-other")
+    assert aotlib.cache_key(fp, "decode", (0,), "sig", SCFG, CFG) != k
+
+
+def test_default_cache_dir_lives_under_the_compile_cache_root(
+        tmp_path, monkeypatch):
+    from repro import compile_cache
+    monkeypatch.delenv("REPRO_AOT_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert aotlib.default_cache_dir() == str(tmp_path / "repro-aot")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert aotlib.default_cache_dir() == os.path.join(
+        compile_cache.REPO_ROOT, ".cache", "jax", "repro-aot")
+    monkeypatch.setenv("REPRO_AOT_CACHE", str(tmp_path / "explicit"))
+    assert aotlib.default_cache_dir() == str(tmp_path / "explicit")
+
+
+def test_compile_cache_enable_sets_only_the_unset_default(tmp_path,
+                                                          monkeypatch):
+    from repro import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None   # JAX reads env
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = compile_cache.enable()
+        assert root.endswith("/.cache/jax")
+        assert jax.config.jax_compilation_cache_dir == root
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_replica_engines_are_placed_on_local_devices(comp):
+    from repro.serve import api
+    devices = jax.local_devices()
+    opts = api.ServeOptions(arch="llama-mini", replicas=3)
+    assert api._replica_device(dataclasses.replace(opts, replicas=1),
+                               0) is None
+    for i in range(3):
+        assert api._replica_device(opts, i) == devices[i % len(devices)]
+    dev = devices[-1]
+    cb = ContinuousBatcher(comp, CFG, SCFG, device=dev)
+    placed = {d for leaf in jax.tree.leaves((cb.params, cb.cache))
+              for d in leaf.devices()}
+    assert placed == {dev}

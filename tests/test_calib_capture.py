@@ -6,6 +6,8 @@ eager fp64 host oracle within 1e-4 relative on EVERY tag, and an engine
 booted from a saved compressed checkpoint must decode token-identically to
 one compressed in-process.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -131,6 +133,25 @@ def test_streaming_moe_expert_capture():
     col = streaming_calibrate(lp, cfg, batches)
     assert any("/expert" in t for t in col.gram)
     _assert_parity(col, oracle)
+
+
+@pytest.mark.parametrize("meshed", [False, True])
+def test_capture_step_takes_weights_as_arguments(meshed):
+    """Closed over, the weights would be baked into the capture executable
+    as constants — gigabytes, and a compile to match, at full width."""
+    params, _ = T.init_model(CFG, jax.random.PRNGKey(0))
+    cal = StreamingCalibrator(
+        to_list_params(params, CFG), CFG,
+        mesh=make_host_mesh(data=1, model=1) if meshed else None)
+    batch = _batches(CFG, n=1)[0]
+    cal.ingest(batch)
+    lowered = (cal._capture.lower(cal._weights, batch) if meshed else
+               cal._step.lower(cal._accs, cal._weights, batch))
+    consts = re.findall(r'constant dense<"0x[0-9A-Fa-f]*"> : '
+                        r'tensor<([0-9x]+)x\w+>', lowered.as_text())
+    smallest = min(w.size for w in cal._weights if w.ndim == 2)
+    assert all(np.prod([int(d) for d in c.split("x")]) < smallest
+               for c in consts), consts
 
 
 def test_discovery_and_ragged_batch_shapes():

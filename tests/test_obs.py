@@ -401,3 +401,17 @@ def test_nondrained_drain_autodumps(params, tmp_path):
     obj = json.loads(open(cb.flight.dumps[0]).read())
     assert obj["reason"] == "stalled"
     assert sorted(obj["context"]["undrained_rids"]) == [0, 1]
+
+
+def test_device_trace_raises_when_the_profiler_cannot_start(monkeypatch,
+                                                            tmp_path):
+    import jax
+
+    def refuse(logdir):
+        raise RuntimeError("profiler unavailable")
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with trace.device_trace(str(tmp_path)):
+            pass
+    with trace.device_trace(None) as off:    # no logdir: no capture asked
+        assert off is None

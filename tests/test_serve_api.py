@@ -227,3 +227,17 @@ def test_factory_retries_kwarg_is_deprecated(artifact):
         eng = api.Engine.from_compressed(d, cfg, scfg, retries=0)
     assert any(issubclass(x.category, DeprecationWarning) for x in w)
     assert isinstance(eng, api.Engine)
+
+
+def test_registered_config_serves_through_the_api():
+    """``configs.register`` makes a cut variant an arch id that every
+    entry point accepts."""
+    from repro import configs
+    configs.register("llama-mini-cut2", CFG)
+    assert get_config("llama_mini_cut2") is CFG
+    opts = api.ServeOptions(arch="llama-mini-cut2", batch=2, max_len=32,
+                            requests=2, prompt_len=4, n_new=3)
+    assert api.load_engine(opts).cfg is CFG
+    res = api.serve(opts)
+    assert res.status == "drained" and len(res) == 2
+    assert all(len(r.out) == 3 for r in res)
