@@ -20,6 +20,15 @@ call sites in hot loops:
   spans, ``i`` instants, ``C`` counters, ``b``/``e`` async request
   spans, ``M`` thread names) that chrome://tracing and
   https://ui.perfetto.dev load directly.
+* **spans can join the profiler's clock** — ``Tracer(annotate=True)``
+  also opens a ``jax.profiler.TraceAnnotation`` of the same name and
+  scalar args around each span, so a device capture
+  (:func:`device_trace`) shows the program's spans on its host plane.
+* **collections are spanned** — while a tracer is installed, a
+  ``gc.callbacks`` hook records each full collection as a ``gc`` span on
+  the thread that triggered it. When a collection runs depends on the
+  allocator, not the program, so ``gc`` spans sit outside the
+  deterministic order (``seq`` -1, like thread-name metadata).
 
 Usage::
 
@@ -36,6 +45,7 @@ TensorBoard/Perfetto artifacts land there); it is a no-op otherwise.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import tempfile
@@ -44,6 +54,10 @@ import time
 from typing import Any, Dict, List, Optional
 
 SCHEMA = "repro.trace/v1"
+GC_SPAN = "gc"
+# the one generation whose collections are spanned: younger ones take well
+# under a millisecond and run every few hundred allocations
+GC_GENERATION = 2
 
 # Chrome trace event phases used here (the subset Perfetto renders):
 # X complete span, i instant, C counter, b/e async begin/end, M metadata.
@@ -68,15 +82,24 @@ NULL_SPAN = _NullSpan()
 
 class _Span:
     """One live ``X`` (complete) event: enter stamps ``ts``, exit stamps
-    ``dur`` and appends the finished event to the tracer."""
+    ``dur`` and appends the finished event to the tracer. With an
+    annotating tracer, a profiler annotation brackets the span."""
 
-    __slots__ = ("_tracer", "_event", "_t0")
+    __slots__ = ("_tracer", "_event", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self._tracer = tracer
         self._event = {"name": name, "ph": "X", "args": args}
+        self._ann = None
 
     def __enter__(self):
+        make = self._tracer._annotation
+        if make is not None:
+            ev = self._event
+            self._ann = make(ev["name"], **{
+                k: v for k, v in ev["args"].items()
+                if isinstance(v, (int, float, str))})
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -87,6 +110,8 @@ class _Span:
         ev["ts"] = (self._t0 - tr.epoch_ns) / 1e3     # Chrome wants µs
         ev["dur"] = (t1 - self._t0) / 1e3
         tr._append(ev)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -98,14 +123,24 @@ class Tracer:
     attached but never used for ordering). ``max_events`` bounds memory;
     overflow drops the *newest* events and counts them, so a runaway
     loop can't OOM the process it is meant to debug.
+
+    ``annotate=True`` brackets every span with a
+    ``jax.profiler.TraceAnnotation`` of its name and int/float/str args,
+    putting it on the profiler's clock when a device capture runs.
     """
 
-    def __init__(self, max_events: int = 1_000_000):
+    def __init__(self, max_events: int = 1_000_000, annotate: bool = False):
         self.epoch_ns = time.perf_counter_ns()
         self.max_events = max_events
+        self._annotation = None
+        if annotate:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation
         self.events: List[Dict[str, Any]] = []
         self.dropped = 0
-        self._lock = threading.Lock()
+        # re-entrant: a collection can start inside _append (it allocates)
+        # and the gc hook's span then appends on the same thread
+        self._lock = threading.RLock()
         self._seq = 0
         self._pid = os.getpid()
         self._named_tids: set = set()
@@ -119,8 +154,11 @@ class Tracer:
                 return
             ev["pid"] = self._pid
             ev["tid"] = tid
-            ev["seq"] = self._seq
-            self._seq += 1
+            if ev["name"] == GC_SPAN:
+                ev["seq"] = -1
+            else:
+                ev["seq"] = self._seq
+                self._seq += 1
             if tid not in self._named_tids:
                 self._named_tids.add(tid)
                 self.events.append(
@@ -180,19 +218,46 @@ class Tracer:
 # Module-global switch
 # ---------------------------------------------------------------------------
 _tracer: Optional[Tracer] = None
+_gc_open = None            # the span of the collection in progress
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    """``gc.callbacks`` hook: a ``gc`` span from the start of a full
+    collection to its stop, opened through the installed tracer's
+    ``span`` (so a subclass that annotates, annotates it too)."""
+    global _gc_open
+    if phase == "start":
+        t = _tracer
+        if t is not None and info["generation"] == GC_GENERATION:
+            _gc_open = t.span(GC_SPAN, generation=info["generation"])
+            _gc_open.__enter__()
+    elif _gc_open is not None:
+        sp, _gc_open = _gc_open, None
+        sp.__exit__(None, None, None)
+
+
+def _install(tracer: Optional[Tracer]) -> None:
+    """Make ``tracer`` the global sink; the gc hook is registered exactly
+    while one is installed."""
+    global _tracer
+    _tracer = tracer
+    hooked = _on_gc in gc.callbacks
+    if tracer is not None and not hooked:
+        gc.callbacks.append(_on_gc)
+    elif tracer is None and hooked:
+        gc.callbacks.remove(_on_gc)
 
 
 def enable(tracer: Optional[Tracer] = None) -> Tracer:
     """Install ``tracer`` (or a fresh one) as the global trace sink."""
-    global _tracer
-    _tracer = tracer if tracer is not None else Tracer()
+    _install(tracer if tracer is not None else Tracer())
     return _tracer
 
 
 def disable() -> Optional[Tracer]:
     """Remove the global tracer and return it (for export)."""
-    global _tracer
-    t, _tracer = _tracer, None
+    t = _tracer
+    _install(None)
     return t
 
 
@@ -241,13 +306,12 @@ def async_end(name: str, aid, **args) -> None:
 def tracing(out: Optional[str] = None, tracer: Optional[Tracer] = None):
     """Enable tracing for a block; on exit restore the previous tracer
     and (with ``out``) write the Chrome-trace JSON there."""
-    global _tracer
     prev = _tracer
     t = enable(tracer)
     try:
         yield t
     finally:
-        _tracer = prev
+        _install(prev)
         if out:
             t.write(out)
 

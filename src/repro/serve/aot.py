@@ -416,22 +416,30 @@ class AotRegistry:
 
     # ---- role functions --------------------------------------------------
     def _role_fn(self, role: str):
-        cfg, scfg = self.cfg, self.scfg
+        """The function compiled for ``role`` and its donated arguments.
+        Each function carries its role's name, which XLA keeps as the
+        executable's module name (``jit_decode``, ``jit_prefill``, …)."""
+        cfg, scfg, T = self.cfg, self.scfg, self._T
         if role == ROLE_DECODE:
-            return lambda p, c, t: self._T.decode_step(p, cfg, c, t), ()
+            def decode(p, c, t):
+                return T.decode_step(p, cfg, c, t)
+            return decode, ()
         if role == ROLE_PREFILL:
-            return (lambda p, b: self._T.prefill(p, cfg, b,
-                                                 max_len=scfg.max_len), ())
+            def prefill(p, b):
+                return T.prefill(p, cfg, b, max_len=scfg.max_len)
+            return prefill, ()
         if role == ROLE_SCATTER:
             return scatter_rows, (0,)
         if role == ROLE_PURGE:
             return purge_rows, (0,)
         if role == ROLE_DECODE_PAGED:
-            return (lambda p, c, t, tbl: self._T.decode_step(
-                p, cfg, c, t, table=tbl), ())
+            def decode_paged(p, c, t, tbl):
+                return T.decode_step(p, cfg, c, t, table=tbl)
+            return decode_paged, ()
         if role == ROLE_PREFILL_EXT:
-            return (lambda p, b, arena, tbl: self._T.prefill_ext(
-                p, cfg, b, arena, tbl), ())
+            def prefill_ext(p, b, arena, tbl):
+                return T.prefill_ext(p, cfg, b, arena, tbl)
+            return prefill_ext, ()
         if role == ROLE_SCATTER_PAGED:
             return scatter_paged, (0,)
         if role == ROLE_PURGE_PAGED:

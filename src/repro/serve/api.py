@@ -386,12 +386,14 @@ def serve(opts: ServeOptions, *,
 
     Observability (DESIGN.md §6): ``trace_out`` records the whole run as
     Chrome-trace JSON (load it in https://ui.perfetto.dev);
-    ``device_trace_dir`` adds a ``jax.profiler`` device capture;
+    ``device_trace_dir`` adds a ``jax.profiler`` device capture, with the
+    program's spans annotated on its host plane (one clock for both);
     ``metrics_json``/``metrics_port`` export the live v2 metrics
     snapshot as periodic JSON / a Prometheus scrape endpoint;
     ``flightrec_dir`` arms per-engine flight-recorder dumps."""
     if opts.trace_out or opts.device_trace_dir:
-        with trace.tracing(out=opts.trace_out or None):
+        tracer = trace.Tracer(annotate=bool(opts.device_trace_dir))
+        with trace.tracing(out=opts.trace_out or None, tracer=tracer):
             with trace.device_trace(opts.device_trace_dir or None):
                 result = _serve_inner(opts, echo=echo)
         if opts.trace_out:

@@ -37,7 +37,9 @@ default construction behaves exactly like the pre-resilience engine):
   code paths production faults would take (chaos suite:
   tests/test_resilience.py).
 * **observability** — every stage is traced (``obs.trace`` spans:
-  admit/prefill/decode_step/purge/poison_probe, per-request async spans,
+  ``engine_step`` and, inside it, admit/prefill/decode_step/
+  logits_wait/sample/emit/purge, each child with the ``step`` of its
+  engine step; poison_probe instants, per-request async spans,
   queue-depth and rung counter tracks) and a flight recorder
   (``obs.flightrec``) rings recent events, auto-dumping an artifact on a
   typed request failure or a non-``drained`` drain (DESIGN.md §6).
@@ -512,7 +514,7 @@ class ContinuousBatcher:
                              queue_depth=depth, step=self._step_idx)
 
     # ---- admission -------------------------------------------------------
-    def _admit(self) -> None:
+    def _admit(self, step: int) -> None:
         free = [i for i, r in enumerate(self.slots) if r is None]
         admit, shed = self.admission.take(len(free), time.perf_counter())
         for req in shed:
@@ -521,17 +523,17 @@ class ContinuousBatcher:
         admit = [r for r in admit if self._check_length(r)]
         if not admit:
             return
-        with trace.span("admit", n=len(admit), level=self.level):
+        with trace.span("admit", step=step, n=len(admit), level=self.level):
             self.flight.note("admit", rids=[r.rid for r in admit],
                              level=self.level)
             if self.paged:
-                n_adm = self._admit_paged(admit, free[:len(admit)])
+                n_adm = self._admit_paged(admit, free[:len(admit)], step)
             elif self.bucketed:
-                self._admit_batched(admit, free[:len(admit)])
+                self._admit_batched(admit, free[:len(admit)], step)
                 n_adm = len(admit)
             else:
                 for req, slot in zip(admit, free):
-                    self._admit_exact(req, slot)
+                    self._admit_exact(req, slot, step)
                 n_adm = len(admit)
         self.stats["admissions"] += 1
         self.stats["admitted"] += n_adm
@@ -574,7 +576,8 @@ class ContinuousBatcher:
             if req is not None and self.faults.rid_is_poison(req.rid):
                 last[j] = np.nan
 
-    def _admit_batched(self, admit: List[Request], free: List[int]) -> None:
+    def _admit_batched(self, admit: List[Request], free: List[int],
+                       step: int) -> None:
         """All admitted prompts in ONE fixed-batch bucketed prefill,
         emitted through the finite guard."""
         B = self.scfg.batch
@@ -587,67 +590,76 @@ class ContinuousBatcher:
             toks[j, :len(req.tokens)] = req.tokens
             lens[j] = len(req.tokens)
             slots[j] = slot
-        with trace.span("prefill", bucket=Sb, n=len(admit),
-                        level=self.level):
+        with trace.span("prefill", step=step, bucket=Sb, n=len(admit),
+                        level=self.level,
+                        real_tokens=int(lens[:len(admit)].sum())):
             logits, c1 = self.exec.prefill(
                 self._params_now(), {"tokens": jnp.asarray(toks),
                                      "lengths": jnp.asarray(lens)},
                 level=self.level, bucket=Sb)
             self.cache = self.exec.scatter(self.cache, c1,
                                            jnp.asarray(slots))
-        last = np.array(logits[:, -1])                 # (B, V) writable host copy
-        if self.faults is not None:
-            for j in self.faults.prefill_rows_to_poison(
-                    self.stats["admissions"], len(admit)):
-                last[j] = np.nan
-        self._poison_rid_rows(admit + [None] * (B - len(admit)), last)
-        finite = np.isfinite(last).all(axis=-1)
-        tok = last.argmax(-1).astype(np.int32)
-        tok[~finite] = 0
-        self.tokens = self.tokens.at[jnp.asarray(slots), 0].set(
-            jnp.asarray(tok), mode="drop")
+        with trace.span("logits_wait", step=step):
+            last = np.array(logits[:, -1])     # (B, V) writable host copy
+        with trace.span("sample", step=step):
+            if self.faults is not None:
+                for j in self.faults.prefill_rows_to_poison(
+                        self.stats["admissions"], len(admit)):
+                    last[j] = np.nan
+            self._poison_rid_rows(admit + [None] * (B - len(admit)), last)
+            finite = np.isfinite(last).all(axis=-1)
+            tok = last.argmax(-1).astype(np.int32)
+            tok[~finite] = 0
+            self.tokens = self.tokens.at[jnp.asarray(slots), 0].set(
+                jnp.asarray(tok), mode="drop")
         bad: List[int] = []
-        now = time.perf_counter()
-        for j, (req, slot) in enumerate(zip(admit, free)):
-            if finite[j]:
-                req.out.append(int(tok[j]))
-                self._emit_token(req, int(tok[j]))
-                req.t_first = req.t_first or now
-                self._metrics.observe_ttft(now - req.t_submit)
-                self.slots[slot] = req
-                self._progress += 1
-            else:
-                bad.append(j)
+        with trace.span("emit", step=step):
+            now = time.perf_counter()
+            for j, (req, slot) in enumerate(zip(admit, free)):
+                if finite[j]:
+                    req.out.append(int(tok[j]))
+                    self._emit_token(req, int(tok[j]))
+                    req.t_first = req.t_first or now
+                    self._metrics.observe_ttft(now - req.t_submit)
+                    self.slots[slot] = req
+                    self._progress += 1
+                else:
+                    bad.append(j)
         if bad:
             ambiguous = len(bad) == len(admit) and len(admit) > 1
             self._purge_slots([free[j] for j in bad])
             self._quarantine([admit[j] for j in bad], ambiguous)
 
-    def _admit_exact(self, req: Request, slot: int) -> None:
+    def _admit_exact(self, req: Request, slot: int, step: int) -> None:
         """Exact-length single-row admission (recurrent-state archs)."""
-        with trace.span("prefill", exact=len(req.tokens),
-                        level=self.level):
+        with trace.span("prefill", step=step, exact=len(req.tokens),
+                        level=self.level, real_tokens=len(req.tokens)):
             logits, c1 = self.exec.prefill(
                 self._params_now(),
                 {"tokens": jnp.asarray(req.tokens[None, :])},
                 level=self.level)
             self.cache = self.exec.scatter(
                 self.cache, c1, jnp.asarray([slot], dtype=np.int32))
-        last = np.array(logits[:, -1])
-        self._poison_rid_rows([req], last)
-        if not np.isfinite(last[0]).all():
+        with trace.span("logits_wait", step=step):
+            last = np.array(logits[:, -1])
+        with trace.span("sample", step=step):
+            self._poison_rid_rows([req], last)
+            finite = bool(np.isfinite(last[0]).all())
+            if finite:
+                t = int(last[0].argmax())
+                self.tokens = self.tokens.at[slot, 0].set(t)
+        if not finite:
             self._purge_slots([slot])
             self._quarantine([req], ambiguous=False)
             return
-        t = int(last[0].argmax())
-        req.out.append(t)
-        self._emit_token(req, t)
-        now = time.perf_counter()
-        req.t_first = req.t_first or now
-        self._metrics.observe_ttft(now - req.t_submit)
-        self.tokens = self.tokens.at[slot, 0].set(t)
-        self.slots[slot] = req
-        self._progress += 1
+        with trace.span("emit", step=step):
+            req.out.append(t)
+            self._emit_token(req, t)
+            now = time.perf_counter()
+            req.t_first = req.t_first or now
+            self._metrics.observe_ttft(now - req.t_submit)
+            self.slots[slot] = req
+            self._progress += 1
 
     # ---- paged admission (DESIGN.md §5.7) --------------------------------
     def _table_jnp(self) -> jax.Array:
@@ -660,7 +672,8 @@ class ContinuousBatcher:
         r.gauge("kv_blocks_in_use").set(self.pool.in_use)
         r.gauge("kv_blocks_peak").set(self.pool.peak_in_use)
 
-    def _admit_paged(self, admit: List[Request], free: List[int]) -> int:
+    def _admit_paged(self, admit: List[Request], free: List[int],
+                     step: int) -> int:
         """Paged admission: plan each request against the prefix cache,
         allocate/refcount its blocks into a table row, COW-fork partial
         matches, then prefill in (at most) two fixed-batch groups —
@@ -746,8 +759,9 @@ class ContinuousBatcher:
                 lens[row] = len(t)
                 starts[row] = start
                 slots[row] = slot
-            with trace.span("prefill", bucket=Sg, n=len(grp),
-                            level=self.level, ext=ext):
+            with trace.span("prefill", step=step, bucket=Sg, n=len(grp),
+                            level=self.level, ext=ext,
+                            real_tokens=int(lens[:len(grp)].sum())):
                 if ext:
                     # arena gather wants the table row of each BATCH row
                     rtbl = jnp.asarray(
@@ -767,41 +781,44 @@ class ContinuousBatcher:
                 self.cache = self.exec.scatter_paged(
                     self.cache, c1, jnp.asarray(slots), tbl,
                     jnp.asarray(starts))
-            gl = np.array(logits[:, -1])
+            with trace.span("logits_wait", step=step):
+                gl = np.array(logits[:, -1])
             for row, j in enumerate(grp):
                 last_rows[j] = gl[row]
-        last = np.stack(last_rows)                     # (n_plans, V)
         reqs = [p[0] for p in plans]
-        if self.faults is not None:
-            for j in self.faults.prefill_rows_to_poison(
-                    self.stats["admissions"], len(plans)):
-                last[j] = np.nan
-        self._poison_rid_rows(reqs, last)
-        finite = np.isfinite(last).all(axis=-1)
-        tok = last.argmax(-1).astype(np.int32)
-        tok[~finite] = 0
-        tokj = np.zeros((B,), dtype=np.int32)
-        slotj = np.full((B,), B, dtype=np.int32)
-        for j, (req, slot, start) in enumerate(plans):
-            tokj[j] = tok[j]
-            slotj[j] = slot
-        self.tokens = self.tokens.at[jnp.asarray(slotj), 0].set(
-            jnp.asarray(tokj), mode="drop")
+        with trace.span("sample", step=step):
+            last = np.stack(last_rows)                 # (n_plans, V)
+            if self.faults is not None:
+                for j in self.faults.prefill_rows_to_poison(
+                        self.stats["admissions"], len(plans)):
+                    last[j] = np.nan
+            self._poison_rid_rows(reqs, last)
+            finite = np.isfinite(last).all(axis=-1)
+            tok = last.argmax(-1).astype(np.int32)
+            tok[~finite] = 0
+            tokj = np.zeros((B,), dtype=np.int32)
+            slotj = np.full((B,), B, dtype=np.int32)
+            for j, (req, slot, start) in enumerate(plans):
+                tokj[j] = tok[j]
+                slotj[j] = slot
+            self.tokens = self.tokens.at[jnp.asarray(slotj), 0].set(
+                jnp.asarray(tokj), mode="drop")
         bad: List[int] = []
-        now = time.perf_counter()
-        for j, (req, slot, start) in enumerate(plans):
-            if finite[j]:
-                req.out.append(int(tok[j]))
-                self._emit_token(req, int(tok[j]))
-                req.t_first = req.t_first or now
-                self._metrics.observe_ttft(now - req.t_submit)
-                self.slots[slot] = req
-                self._progress += 1
-                if self.prefix is not None:
-                    self.prefix.register(np.asarray(req.tokens),
-                                         self.table[slot], self.pool)
-            else:
-                bad.append(j)
+        with trace.span("emit", step=step):
+            now = time.perf_counter()
+            for j, (req, slot, start) in enumerate(plans):
+                if finite[j]:
+                    req.out.append(int(tok[j]))
+                    self._emit_token(req, int(tok[j]))
+                    req.t_first = req.t_first or now
+                    self._metrics.observe_ttft(now - req.t_submit)
+                    self.slots[slot] = req
+                    self._progress += 1
+                    if self.prefix is not None:
+                        self.prefix.register(np.asarray(req.tokens),
+                                             self.table[slot], self.pool)
+                else:
+                    bad.append(j)
         if bad:
             ambiguous = len(bad) == len(plans) and len(plans) > 1
             self._purge_slots([plans[j][1] for j in bad],
@@ -859,7 +876,7 @@ class ContinuousBatcher:
         another request or the cache still holds are never zeroed — the
         other holders' content is untouched by the poisoned row), and
         mark the rows dead."""
-        with trace.span("purge", rows=list(rows)):
+        with trace.span("purge", step=self._step_idx - 1, rows=list(rows)):
             B = self.scfg.batch
             pad = np.full((B,), B, dtype=np.int32)
             pad[:len(rows)] = rows
@@ -1009,12 +1026,16 @@ class ContinuousBatcher:
         self._metrics.observe_queue_depth(len(self.queue))
         trace.counter("serve", queue_depth=len(self.queue),
                       rank_level=self.level)
-        self._admit()
+        self._admit(idx)
         live = [i for i, r in enumerate(self.slots) if r is not None]
         if not live:
             return 0
+        # the context each live slot attends this step (prompt and tokens
+        # so far), counted only for the span
+        ctx = (sum(len(self.slots[i].tokens) + len(self.slots[i].out)
+                   for i in live) if trace.enabled() else 0)
         with trace.span("decode_step", step=idx, live=len(live),
-                        level=self.level):
+                        level=self.level, ctx_tokens=ctx):
             if self.paged:
                 logits, self.cache = self.exec.decode_paged(
                     self._params_now(), self.cache, self.tokens,
@@ -1023,36 +1044,39 @@ class ContinuousBatcher:
                 logits, self.cache = self.exec.decode(
                     self._params_now(), self.cache, self.tokens,
                     level=self.level)
-        last = np.array(logits[:, -1])                 # (B, V) writable host copy
-        if self.faults is not None:
-            for row in self.faults.decode_rows_to_poison(idx, live):
-                last[row] = np.nan
-        self._poison_rid_rows(self.slots, last)
-        finite = np.isfinite(last).all(axis=-1)
-        nxt = last.argmax(-1).astype(np.int32)
-        good = [i for i in live if finite[i]]
-        bad = [i for i in live if not finite[i]]
-        nxt[~finite] = 0                     # poisoned tokens never emitted
-        self.tokens = jnp.asarray(nxt[:, None])
-        retired_rows: List[int] = []
-        retired_reqs: List[Request] = []
-        for i in good:
-            req = self.slots[i]
-            req.out.append(int(nxt[i]))
-            self._emit_token(req, int(nxt[i]))
-            self._progress += 1
-            if len(req.out) >= req.n_new:
-                req.t_done = time.perf_counter()
-                req.status = adm.DONE
-                self._metrics.bump("completed")
-                self.done.append(req)
-                self.slots[i] = None
-                if self.paged:
-                    retired_rows.append(i)
-                    retired_reqs.append(req)
-                self._emit_terminal(req)
-        if retired_rows:
-            self._release_retired(retired_rows, retired_reqs)
+        with trace.span("logits_wait", step=idx):
+            last = np.array(logits[:, -1])     # (B, V) writable host copy
+        with trace.span("sample", step=idx):
+            if self.faults is not None:
+                for row in self.faults.decode_rows_to_poison(idx, live):
+                    last[row] = np.nan
+            self._poison_rid_rows(self.slots, last)
+            finite = np.isfinite(last).all(axis=-1)
+            nxt = last.argmax(-1).astype(np.int32)
+            good = [i for i in live if finite[i]]
+            bad = [i for i in live if not finite[i]]
+            nxt[~finite] = 0                 # poisoned tokens never emitted
+            self.tokens = jnp.asarray(nxt[:, None])
+        with trace.span("emit", step=idx, n=len(good)):
+            retired_rows: List[int] = []
+            retired_reqs: List[Request] = []
+            for i in good:
+                req = self.slots[i]
+                req.out.append(int(nxt[i]))
+                self._emit_token(req, int(nxt[i]))
+                self._progress += 1
+                if len(req.out) >= req.n_new:
+                    req.t_done = time.perf_counter()
+                    req.status = adm.DONE
+                    self._metrics.bump("completed")
+                    self.done.append(req)
+                    self.slots[i] = None
+                    if self.paged:
+                        retired_rows.append(i)
+                        retired_reqs.append(req)
+                    self._emit_terminal(req)
+            if retired_rows:
+                self._release_retired(retired_rows, retired_reqs)
         if bad:
             ambiguous = len(bad) == len(live) and len(live) > 1
             reqs = [self.slots[i] for i in bad]

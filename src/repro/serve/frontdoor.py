@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs import trace
 from repro.serve.engine import ContinuousBatcher, DrainResult, Request
 
 _END = object()          # stream sentinel
@@ -189,7 +190,8 @@ class FrontDoor:
 
     def _loop(self) -> None:
         while True:
-            moved = self._pump_intake()
+            with trace.span("intake"):
+                moved = self._pump_intake()
             stepped = self.batcher.step()
             busy = (moved or stepped or self.batcher.queue
                     or any(s is not None for s in self.batcher.slots)

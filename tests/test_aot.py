@@ -182,3 +182,21 @@ def test_replica_engines_are_placed_on_local_devices(comp):
     placed = {d for leaf in jax.tree.leaves((cb.params, cb.cache))
               for d in leaf.devices()}
     assert placed == {dev}
+
+
+def test_compiled_executables_are_named_for_their_role(tmp_path, comp):
+    """The role functions carry their role's name, so XLA names each
+    executable for it (``jit_decode``, not ``jit__lambda_``) — compiled
+    now, and deserialized from the cache on the next boot."""
+    cache = aotlib.AotRegistry(CFG, SCFG, "x")._cache_aval()
+    tok = jax.ShapeDtypeStruct((SCFG.batch, 1), np.int32)
+    batch = {"tokens": jax.ShapeDtypeStruct((SCFG.batch, 8), np.int32),
+             "lengths": jax.ShapeDtypeStruct((SCFG.batch,), np.int32)}
+    entries = ((aotlib.ROLE_DECODE, (0,), (comp, cache, tok)),
+               (aotlib.ROLE_PREFILL, (0, 8), (comp, batch)))
+    for boot in range(2):
+        reg = _registry(comp, tmp_path)
+        for role, variant, args in entries:
+            head = reg._resolve(role, variant, args).as_text().split(",")[0]
+            assert head == f"HloModule jit_{role}"
+        assert reg.stats["aot_cache_hits"] == 2 * boot

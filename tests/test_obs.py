@@ -16,6 +16,8 @@ The contracts under test:
 * a typed poison failure auto-dumps a flight-recorder artifact that
   identifies the poisoned rid and the rung it failed at.
 """
+import gc
+import glob
 import json
 import threading
 import urllib.request
@@ -221,6 +223,148 @@ def test_frontdoor_engine_thread_traces_cleanly(params):
     assert trace.validate_chrome_trace(t.to_chrome()) == []
     meta = {e["args"]["name"] for e in t.events if e["ph"] == "M"}
     assert "serve-engine" in meta
+
+
+def test_engine_step_children_lie_inside_their_engine_step(params):
+    """Every child the host spends an engine step in names that step by
+    ``step`` and lies inside the ``engine_step`` span of the same
+    index; the program's own counts ride on the role spans."""
+    reqs = make_requests()
+    with trace.tracing() as t:
+        cb = ContinuousBatcher(params, CFG, SCFG)
+        for r in reqs:
+            cb.submit(r)
+        res = cb.run_until_drained()
+    assert res.status == "drained"
+    spans = [e for e in t.events if e["ph"] == "X"]
+    steps = {e["args"]["step"]: e for e in spans
+             if e["name"] == "engine_step"}
+    children = [e for e in spans
+                if e["name"] in ("logits_wait", "sample", "emit")]
+    decoded = [e["args"]["step"] for e in spans
+               if e["name"] == "decode_step"]
+    # one decode and one admission of each per step that ran them
+    assert len(children) == 3 * (len(decoded) + cb.stats["admissions"])
+    for e in children:
+        outer = steps[e["args"]["step"]]
+        assert outer["ts"] <= e["ts"] + 1e-3
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    for name in ("admit", "prefill", "decode_step"):
+        assert all(e["args"]["step"] in steps for e in spans
+                   if e["name"] == name)
+    prefills = [e for e in spans if e["name"] == "prefill"]
+    assert sum(e["args"]["real_tokens"] for e in prefills) == sum(
+        len(r.tokens) for r in reqs)
+    # each request decodes n_new - 1 tokens, the j-th attending its
+    # prompt and the j tokens before it
+    assert sum(e["args"]["ctx_tokens"] for e in spans
+               if e["name"] == "decode_step") == sum(
+        len(r.tokens) + j for r in reqs for j in range(1, r.n_new))
+
+
+class _Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what each
+    annotation was opened with, and whether it was entered and left."""
+    opened = []
+
+    def __init__(self, name, **args):
+        self.row = [name, args, 0, 0]
+        _Recorder.opened.append(self.row)
+
+    def __enter__(self):
+        self.row[2] += 1
+        return self
+
+    def __exit__(self, *exc):
+        self.row[3] += 1
+        return False
+
+
+def test_annotating_tracer_opens_one_annotation_per_span(monkeypatch):
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+    monkeypatch.setattr(_Recorder, "opened", [])
+    t = trace.Tracer(annotate=True)
+    with t.span("engine_step", step=3):
+        with t.span("decode_step", step=3, live=2, level=0, rows=[1, 2],
+                    frac=0.5, tag="x"):
+            pass
+    assert _Recorder.opened == [
+        ["engine_step", {"step": 3}, 1, 1],
+        ["decode_step", {"step": 3, "live": 2, "level": 0, "frac": 0.5,
+                         "tag": "x"}, 1, 1]]
+    assert [e["name"] for e in t.events if e["ph"] == "X"] == [
+        "decode_step", "engine_step"]
+    monkeypatch.setattr(_Recorder, "opened", [])
+    plain = trace.Tracer()
+    with plain.span("decode_step", step=1):
+        pass
+    assert _Recorder.opened == [] and len(plain.events) == 2
+
+
+def test_gc_hook_is_registered_only_while_tracing():
+    assert trace._on_gc not in gc.callbacks
+    t = trace.enable()
+    try:
+        assert gc.callbacks.count(trace._on_gc) == 1
+        trace.enable(t)                    # re-installing adds no second
+        assert gc.callbacks.count(trace._on_gc) == 1
+        gc.collect()
+    finally:
+        trace.disable()
+    assert trace._on_gc not in gc.callbacks
+    collected = [e for e in t.events if e["name"] == trace.GC_SPAN]
+    assert collected and all(
+        e["ph"] == "X" and e["seq"] == -1
+        and e["args"]["generation"] == trace.GC_GENERATION
+        for e in collected)
+    with trace.tracing():
+        assert trace._on_gc in gc.callbacks
+    assert trace._on_gc not in gc.callbacks
+
+
+def test_a_collection_inside_an_append_does_not_deadlock():
+    """A collection can start while ``_append`` holds the tracer's lock
+    (the append allocates); the ``gc`` span it closes then appends on
+    the same thread, which must not wait on itself."""
+    t = trace.enable()
+    try:
+        def collect_inside_append():
+            with t._lock:
+                trace._on_gc("start", {"generation": trace.GC_GENERATION})
+                trace._on_gc("stop", {"generation": trace.GC_GENERATION})
+        th = threading.Thread(target=collect_inside_append, daemon=True)
+        th.start()
+        th.join(timeout=10.0)
+        assert not th.is_alive()
+    finally:
+        trace.disable()
+    assert {e["name"] for e in t.events if e["ph"] == "X"} == {
+        trace.GC_SPAN}
+
+
+def test_serve_device_trace_holds_the_programs_spans(tmp_path):
+    """``--device-trace-dir`` alone puts the program's spans on the
+    profiler's host plane, on the device trace's own clock."""
+    from jax.profiler import ProfileData
+
+    from repro.serve import api
+    d = tmp_path / "prof"
+    api.serve(api.ServeOptions(
+        arch="llama-mini", requests=2, n_new=3, prompt_len=8, batch=2,
+        max_len=32, trace_out=str(tmp_path / "t.json"),
+        device_trace_dir=str(d)))
+    paths = glob.glob(str(d / "**" / "*.xplane.pb"), recursive=True)
+    assert len(paths) == 1
+    found = {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("engine_step", "decode_step"):
+                        found.setdefault(e.name, dict(e.stats))
+    assert set(found) == {"engine_step", "decode_step"}
+    assert "step" in found["engine_step"] and "live" in found["decode_step"]
+    assert not trace.enabled()
 
 
 # ---------------------------------------------------------------------------
