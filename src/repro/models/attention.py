@@ -381,6 +381,38 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, window: int,
     }
 
 
+def _attend_stored_and_new(cfg: ModelConfig, q: jax.Array, k: jax.Array,
+                           v: jax.Array, valid: jax.Array, k_new: jax.Array,
+                           v_new: jax.Array) -> jax.Array:
+    """One query per sequence against its stored cache positions plus its
+    own new K/V, which the cache does not hold yet. q: (B,1,H,hd); k/v:
+    (B,T,K,hd); valid: (B,T) bool; k_new/v_new: (B,1,K,hd). One softmax
+    over [stored | new], weights cast to v's dtype as in ``_sdpa``.
+    Returns (B,1,H*hd)."""
+    B, _, H, hd = q.shape
+    K = k.shape[2]
+    G = H // K
+    scale = hd ** -0.5
+    qg = q.reshape(B, 1, K, G, hd)
+    s = jnp.einsum("bskgh,btkh->bkgst", qg, k,
+                   preferred_element_type=jnp.float32) * scale
+    s_new = jnp.einsum("bskgh,btkh->bkgst", qg, k_new,
+                       preferred_element_type=jnp.float32) * scale
+    s = softcap(s, cfg.attn_logit_softcap)
+    s_new = softcap(s_new, cfg.attn_logit_softcap)
+    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
+    e = jnp.exp(s - m)
+    e_new = jnp.exp(s_new - m)
+    den = jnp.sum(e, axis=-1, keepdims=True) + e_new
+    w, w_new = (e / den).astype(v.dtype), (e_new / den).astype(v.dtype)
+    out = (jnp.einsum("bkgst,btkh->bskgh", w, v,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgst,btkh->bskgh", w_new, v_new,
+                        preferred_element_type=jnp.float32))
+    return out.astype(v.dtype).reshape(B, 1, H * hd)
+
+
 def attend_decode(p: Dict, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
                   cache: Dict, angles: Optional[jax.Array], *,
                   window: int = 0,
@@ -388,37 +420,38 @@ def attend_decode(p: Dict, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
                   table: Optional[jax.Array] = None,
                   ) -> Tuple[jax.Array, Dict]:
     """x: (B,1,D); pos: (B,) int32 per-sequence positions of the new token
-    (-1 marks a dead/purged slot: nothing is written for it and its output
-    row is exact zeros). With ``table`` (B, NB) int32 the cache is a paged
-    arena — k/v leaves (P, bk, K, hd), logical block j of row b living in
-    physical block table[b, j] (full-cache layout only).
-    Returns (out, cache)."""
+    (-1 marks a dead/purged slot: its output row is exact zeros). With
+    ``table`` (B, NB) int32 the cache is a paged arena — k/v leaves (P, bk,
+    K, hd), logical block j of row b living in physical block table[b, j]
+    (full-cache layout only).
+
+    The cache is only read: each query attends its slot's stored positions
+    before ``pos`` plus its own new K/V. Returns (out, row), ``row`` the
+    new token's {"k", "v"} (B, K, hd) at the cache dtype, for
+    ``write_kv_rows`` to put into the pool once every layer has read it."""
     B = x.shape[0]
     if cross_kv is not None:
         q = _split_heads(apply_linear(p["wq"], x), cfg.n_heads, cfg.head_dim)
         k, v = cross_kv     # precomputed (B, T_enc, K, hd)
         mask = jnp.ones((B, 1, 1, k.shape[1]), dtype=bool)
         out = _sdpa(cfg, q, k, v, mask)
-        return apply_linear(p["wo"], out), cache
+        return apply_linear(p["wo"], out), {}
 
     q, k_new, v_new = _qkv(p, cfg, x, angles)
-    rows = jnp.arange(B)
+    k_new = k_new.astype(cache["k"].dtype)
+    v_new = v_new.astype(cache["v"].dtype)
+    row = {"k": k_new[:, 0], "v": v_new[:, 0]}
     if table is not None:
         assert not window, "paged cache is full-layout only"
-        P, bkb = cache["k"].shape[0], cache["k"].shape[1]
-        NB = table.shape[1]
-        safe = jnp.maximum(pos, 0)
-        # dead rows (pos < 0) and positions past the table target the
-        # sentinel block P: the scatter drops them (OOB + mode='drop')
-        pb = jnp.where((pos >= 0) & (safe // bkb < NB),
-                       table[rows, jnp.minimum(safe // bkb, NB - 1)], P)
-        off = safe % bkb
-        k = cache["k"].at[pb, off].set(
-            k_new[:, 0].astype(cache["k"].dtype), mode="drop")
-        v = cache["v"].at[pb, off].set(
-            v_new[:, 0].astype(cache["v"].dtype), mode="drop")
         if use_pallas():
+            # the kernel reads the new token from the arena: this layer's
+            # copy of it, with the row written (the pool itself is written
+            # once, by write_kv_rows)
             from repro.kernels import ops as kops
+            blk, off, live = _paged_target(cache["k"].shape[1], table, pos)
+            pb = jnp.where(live, blk, cache["k"].shape[0])   # P: dropped
+            k = cache["k"].at[pb, off].set(k_new[:, 0], mode="drop")
+            v = cache["v"].at[pb, off].set(v_new[:, 0], mode="drop")
             out = kops.decode_attention_paged(
                 q[:, 0], k, v, pos + 1, table,
                 softcap=cfg.attn_logit_softcap)
@@ -427,26 +460,24 @@ def attend_decode(p: Dict, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
             # gather the arena back into the contiguous (B, NB*bk) layout:
             # same shapes and values as the contiguous path for every live
             # position, so the einsum results are bit-identical to it
+            bkb, NB = cache["k"].shape[1], table.shape[1]
             L = NB * bkb
-            kc = k[table].reshape(B, L, *k.shape[2:])
-            vc = v[table].reshape(B, L, *v.shape[2:])
-            valid = jnp.arange(L)[None, :] <= pos[:, None]
-            out = _sdpa(cfg, q, kc, vc, valid[:, None, None, :])
+            kc = cache["k"][table].reshape(B, L, *cache["k"].shape[2:])
+            vc = cache["v"][table].reshape(B, L, *cache["v"].shape[2:])
+            valid = jnp.arange(L)[None, :] < pos[:, None]
+            out = _attend_stored_and_new(cfg, q, kc, vc, valid, k_new, v_new)
         out = jnp.where((pos >= 0)[:, None, None], out, 0.0)
-        out = apply_linear(p["wo"], out)
-        return out, {"k": k, "v": v}
+        return apply_linear(p["wo"], out), row
 
     L = cache["k"].shape[1]
-    # dead rows (pos = -1) park their write at slot 0 of their own row —
-    # masked by length 0 downstream, fully overwritten on slot reuse
-    slot = jnp.mod(pos, L) if window else jnp.maximum(pos, 0)  # (B,)
-    k = cache["k"].at[rows, slot].set(k_new[:, 0].astype(cache["k"].dtype))
-    v = cache["v"].at[rows, slot].set(v_new[:, 0].astype(cache["v"].dtype))
-    k = constrain(k, "batch", "kv_seq" if not window else None, None, None)
-    v = constrain(v, "batch", "kv_seq" if not window else None, None, None)
     if use_pallas():
-        # ragged decode kernel: per-slot lengths, block-skipped dead cache
+        # ragged decode kernel: per-slot lengths, block-skipped dead cache;
+        # it reads the new token from this layer's copy of the cache
         from repro.kernels import ops as kops
+        rows = jnp.arange(B)
+        slot = jnp.mod(pos, L) if window else jnp.maximum(pos, 0)
+        k = cache["k"].at[rows, slot].set(k_new[:, 0])
+        v = cache["v"].at[rows, slot].set(v_new[:, 0])
         out = kops.decode_attention(q[:, 0], k, v, pos + 1, window=window,
                                     softcap=cfg.attn_logit_softcap)
         out = out.reshape(B, 1, cfg.q_dim)
@@ -454,18 +485,70 @@ def attend_decode(p: Dict, cfg: ModelConfig, x: jax.Array, pos: jax.Array,
         kpos = jnp.arange(L)[None, :]                  # (1, L)
         pcol = pos[:, None]
         if window:
-            # ring buffer: valid slots hold positions in (pos-window, pos]
+            # ring buffer: slot s holds position pos - age, age in [1, L)
+            # (age 0 is the slot the new token will take)
             age = jnp.mod(pcol - kpos, L)
-            valid = age < jnp.minimum(pcol + 1, L)
+            valid = (age >= 1) & (age <= pcol)
         else:
-            valid = kpos <= pcol
-        mask = valid[:, None, None, :]                 # (B,1,1,L)
-        out = _sdpa(cfg, q, k, v, mask)
-        # dead rows have an all-masked score row; match the kernel's
-        # exact-zero emit instead of softmax-uniform junk
+            valid = kpos < pcol
+        k = constrain(cache["k"], "batch", "kv_seq" if not window else None,
+                      None, None)
+        v = constrain(cache["v"], "batch", "kv_seq" if not window else None,
+                      None, None)
+        out = _attend_stored_and_new(cfg, q, k, v, valid, k_new, v_new)
+        # dead rows (pos = -1) attend nothing stored; match the kernel's
+        # exact-zero emit instead of the new token's own value
         out = jnp.where((pos >= 0)[:, None, None], out, 0.0)
-    out = apply_linear(p["wo"], out)
-    return out, {"k": k, "v": v}
+    return apply_linear(p["wo"], out), row
+
+
+def _paged_target(bkb: int, table: jax.Array, pos: jax.Array):
+    """Where row b's token at ``pos`` lives in a paged arena of ``bkb``-
+    token blocks: (physical block, offset, live). Dead rows (pos < 0) and
+    positions past the table are not live."""
+    NB = table.shape[1]
+    safe = jnp.maximum(pos, 0)
+    live = (pos >= 0) & (safe // bkb < NB)
+    blk = table[jnp.arange(table.shape[0]), jnp.minimum(safe // bkb, NB - 1)]
+    return blk, safe % bkb, live
+
+
+def write_kv_rows(pool: Dict, rows: Dict, pos: jax.Array, *,
+                  window: int = 0,
+                  table: Optional[jax.Array] = None) -> Dict:
+    """Write one decode step's new K/V into a run's pool, in place.
+
+    pool: {"k", "v"} leaves (n, B, L, K, hd), or (n, P, bk, K, hd) with
+    ``table``; rows: the layers' ``attend_decode`` rows stacked, leaves
+    (n, B, K, hd). Row b goes to slot ``pos`` (``pos mod L`` for a ring)
+    of its own slot, or through the block table.
+
+    One dynamic-update-slice per slot and leaf, each (n, 1, 1, K, hd): XLA
+    updates a donated pool in place, in the layout it already has. A
+    scatter over the slot axes, or reading the old row back, made it
+    relayout the whole pool instead. So a row that must not land (a dead
+    slot, pos < 0, or a position past its table) writes zeros where zeros
+    already are: slot 0 of its own dead row (init and purge zero a row
+    whose pos they set to -1), or the paged arena's null block 0, which is
+    never allocated."""
+    if table is not None:
+        blk, off, live = _paged_target(pool["k"].shape[2], table, pos)
+        blk, off = jnp.where(live, blk, 0), jnp.where(live, off, 0)
+    else:
+        L = pool["k"].shape[2]
+        live = pos >= 0
+        blk = jnp.arange(pos.shape[0], dtype=jnp.int32)
+        off = jnp.where(live, jnp.mod(pos, L) if window else pos, 0)
+
+    def one(leaf, new):
+        zero = jnp.zeros((), jnp.int32)
+        new = jnp.where(live[None, :, None, None], new, 0).astype(leaf.dtype)
+        for b in range(pos.shape[0]):
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, new[:, b][:, None, None], (zero, blk[b], off[b], zero,
+                                                 zero))
+        return leaf
+    return {"k": one(pool["k"], rows["k"]), "v": one(pool["v"], rows["v"])}
 
 
 def _cache_slots(k: jax.Array, lengths: jax.Array, L: int,

@@ -31,7 +31,8 @@ from repro.dist.sharding import constrain
 from repro.models import mamba, rotary, ssm
 from repro.models.attention import (attend_decode, attend_full,
                                     attend_prefill, attend_prefill_ext,
-                                    init_attention, init_kv_cache)
+                                    init_attention, init_kv_cache,
+                                    write_kv_rows)
 from repro.models.mlp import apply_mlp, apply_moe, init_mlp, init_moe
 from repro.models.params import (Builder, Params, apply_linear, rms_norm,
                                  softcap)
@@ -379,35 +380,36 @@ def _block_decode(kind: str, cfg: ModelConfig, p: Params, cache: Dict,
                   x: jax.Array, pos: jax.Array,
                   angles: Optional[jax.Array],
                   table: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
-    new_cache: Dict[str, Any] = {}
+    """One layer of the decode step. Reads the layer's cache; returns the
+    new x and the layer's update: the new token's K/V row under "kv"
+    (written into the pool after the layer loop), and the new recurrent
+    states in full."""
+    upd: Dict[str, Any] = {}
     h = rms_norm(p["ln1"], x, cfg.norm_eps)
     win = _kind_window(cfg, kind)
     if kind in ("attn", "swa"):
-        out, kv = attend_decode(p["attn"], cfg, h, pos, cache["kv"], angles,
-                                window=win, table=table)
+        out, upd["kv"] = attend_decode(p["attn"], cfg, h, pos, cache["kv"],
+                                       angles, window=win, table=table)
         x = x + out
-        new_cache["kv"] = kv
     elif kind in ("hymba", "hymba_g"):
-        a, kv = attend_decode(p["attn"], cfg, h, pos, cache["kv"], angles,
-                              window=win, table=table)
-        s, sst = mamba.decode_ssm(p["ssm"], cfg, h, cache["ssm"])
+        a, upd["kv"] = attend_decode(p["attn"], cfg, h, pos, cache["kv"],
+                                     angles, window=win, table=table)
+        s, upd["ssm"] = mamba.decode_ssm(p["ssm"], cfg, h, cache["ssm"])
         x = x + mamba.hymba_combine(p, cfg, a, s)
-        new_cache["kv"], new_cache["ssm"] = kv, sst
     elif kind == "mlstm":
-        out, mst = ssm.decode_mlstm(p["mlstm"], cfg, h, cache["mlstm"])
+        out, upd["mlstm"] = ssm.decode_mlstm(p["mlstm"], cfg, h,
+                                             cache["mlstm"])
         x = x + out
-        new_cache["mlstm"] = mst
     elif kind == "slstm":
-        out, sst = ssm.decode_slstm(p["slstm"], cfg, h, cache["slstm"])
+        out, upd["slstm"] = ssm.decode_slstm(p["slstm"], cfg, h,
+                                             cache["slstm"])
         x = x + out
-        new_cache["slstm"] = sst
     if "ln_cross" in p and "cross_kv" in cache:
         h = rms_norm(p["ln_cross"], x, cfg.norm_eps)
         ckv = (cache["cross_kv"]["k"], cache["cross_kv"]["v"])
         out, _ = attend_decode(p["cross"], cfg, h, pos, {}, None,
                                cross_kv=ckv)
         x = x + out
-        new_cache["cross_kv"] = cache["cross_kv"]
     if "ln2" in p:
         h = rms_norm(p["ln2"], x, cfg.norm_eps)
         if "moe" in p:
@@ -415,7 +417,7 @@ def _block_decode(kind: str, cfg: ModelConfig, p: Params, cache: Dict,
             x = x + out
         elif "mlp" in p:
             x = x + apply_mlp(p["mlp"], cfg, h)
-    return x, new_cache
+    return x, upd
 
 
 def decode_step(params: Params, cfg: ModelConfig, cache: Dict,
@@ -428,6 +430,11 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Dict,
     init_cache_paged) and every KV read/write indirects through it.
     Dead slots (pos = -1) neither advance nor write: their logits row is
     whatever the dead residual stream produces and is ignored upstream.
+
+    The layers only read the KV pool; each run's new K/V rows are written
+    after its layer loop, one row per slot (``write_kv_rows``). So a
+    caller that donates ``cache`` gets the pool updated in place: the
+    step writes 2 × layers × slots rows and copies nothing else.
     Returns (logits (B,1,V), new cache)."""
     pos = cache["pos"]
     if tokens_or_embeds.dtype in (jnp.int32, jnp.int64):
@@ -449,31 +456,28 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Dict,
         run_p = params["decoder"][f"run{r}"]
         run_c = cache["runs"][f"run{r}"]
 
-        if isinstance(run_p, list):
-            ncs = []
-            for i, pl in enumerate(run_p):
-                cl = jax.tree.map(lambda a: a[i], run_c)
-                x, nc = _block_decode(kind, cfg, pl, cl, x, pos, angles,
-                                      table)
-                ncs.append(nc)
-            new_runs[f"run{r}"] = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
-        elif not cfg.scan_layers:
-            ncs = []
+        def layer(pl, cl, xx):
+            return _block_decode(kind, cfg, pl, cl, xx, pos, angles, table)
+
+        if isinstance(run_p, list) or not cfg.scan_layers:
+            ups = []
             for i in range(n):
-                pl = jax.tree.map(lambda a: a[i], run_p)
+                pl = (run_p[i] if isinstance(run_p, list)
+                      else jax.tree.map(lambda a: a[i], run_p))
                 cl = jax.tree.map(lambda a: a[i], run_c)
-                x, nc = _block_decode(kind, cfg, pl, cl, x, pos, angles,
-                                      table)
-                ncs.append(nc)
-            new_runs[f"run{r}"] = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
+                x, u = layer(pl, cl, x)
+                ups.append(u)
+            upd = jax.tree.map(lambda *a: jnp.stack(a), *ups)
         else:
             def body(xx, pc):
-                pl, cl = pc
-                xx, nc = _block_decode(kind, cfg, pl, cl, xx, pos, angles,
-                                       table)
-                return xx, nc
-            x, nc = jax.lax.scan(body, x, (run_p, run_c))
-            new_runs[f"run{r}"] = nc
+                return layer(pc[0], pc[1], xx)
+            x, upd = jax.lax.scan(body, x, (run_p, run_c))
+        entry = dict(run_c, **upd)
+        if "kv" in upd:
+            entry["kv"] = write_kv_rows(run_c["kv"], upd["kv"], pos,
+                                        window=_kind_window(cfg, kind),
+                                        table=table)
+        new_runs[f"run{r}"] = entry
     logits = lm_logits(params, cfg, x)
     # dead slots (pos = -1) stay dead; live slots advance
     return logits, {"runs": new_runs,

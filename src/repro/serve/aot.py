@@ -148,6 +148,17 @@ def purge_paged(pool: Dict, rows: jax.Array, blocks: jax.Array) -> Dict:
     return {"runs": runs, "pos": pos}
 
 
+def _hand_over(given: Dict, new: Dict) -> Dict:
+    """A decode executable consumes the pool it is given (``runs``, the
+    donated argument, updated in place into ``new``'s; positions are not
+    donated). Point the caller's dict at the updated pool, so that a
+    reference kept from before the step holds live buffers (the updated
+    pool beside its own, older ``pos``) and never deleted ones. Returns
+    ``new``."""
+    given["runs"] = new["runs"]
+    return new
+
+
 def copy_blocks(pool: Dict, src: jax.Array, dst: jax.Array) -> Dict:
     """Copy-on-write fork: arena block src[j] → dst[j] for each j. The
     destination blocks are freshly allocated (refcount 1, unshared), so
@@ -289,9 +300,9 @@ class TracedRegistry:
         for k in ("prefill_retraces", "decode_retraces", "scatter_retraces"):
             self.stats.setdefault(k, 0)
 
-        def _decode_fn(p, c, t):
+        def _decode_fn(p, pool, pos, t):
             self.stats["decode_retraces"] += 1
-            return T.decode_step(p, cfg, c, t)
+            return T.decode_step(p, cfg, {"runs": pool, "pos": pos}, t)
 
         def _prefill_fn(p, b):
             self.stats["prefill_retraces"] += 1
@@ -301,9 +312,10 @@ class TracedRegistry:
             self.stats["scatter_retraces"] += 1
             return scatter_rows(pool, src, slots)
 
-        def _decode_paged_fn(p, c, t, tbl):
+        def _decode_paged_fn(p, pool, pos, t, tbl):
             self.stats["decode_retraces"] += 1
-            return T.decode_step(p, cfg, c, t, table=tbl)
+            return T.decode_step(p, cfg, {"runs": pool, "pos": pos}, t,
+                                 table=tbl)
 
         def _prefill_ext_fn(p, b, arena, tbl):
             self.stats["prefill_retraces"] += 1
@@ -313,11 +325,12 @@ class TracedRegistry:
             self.stats["scatter_retraces"] += 1
             return scatter_paged(pool, src, slots, tbl, starts)
 
-        self._decode = jax.jit(_decode_fn)
+        # decode consumes the pool it is given: updated in place
+        self._decode = jax.jit(_decode_fn, donate_argnums=(1,))
         self._prefill = jax.jit(_prefill_fn)
         self._scatter = jax.jit(_scatter_fn, donate_argnums=(0,))
         self._purge = jax.jit(purge_rows, donate_argnums=(0,))
-        self._decode_paged = jax.jit(_decode_paged_fn)
+        self._decode_paged = jax.jit(_decode_paged_fn, donate_argnums=(1,))
         # the arena rides along read-only (prefix gathers); not donated
         self._prefill_ext = jax.jit(_prefill_ext_fn)
         self._scatter_paged = jax.jit(_scatter_paged_fn, donate_argnums=(0,))
@@ -334,7 +347,9 @@ class TracedRegistry:
     # role dispatch — variant hints are accepted (and ignored) so the
     # engine calls both registries identically
     def decode(self, params, cache, tokens, *, level: int = 0):
-        return self._decode(params, cache, tokens)
+        logits, new = self._decode(params, cache["runs"], cache["pos"],
+                                   tokens)
+        return logits, _hand_over(cache, new)
 
     def prefill(self, params, batch, *, level: int = 0, bucket=None):
         return self._prefill(params, batch)
@@ -346,7 +361,9 @@ class TracedRegistry:
         return self._purge(pool, rows)
 
     def decode_paged(self, params, cache, tokens, table, *, level: int = 0):
-        return self._decode_paged(params, cache, tokens, table)
+        logits, new = self._decode_paged(params, cache["runs"], cache["pos"],
+                                         tokens, table)
+        return logits, _hand_over(cache, new)
 
     def prefill_ext(self, params, batch, arena, table, *, level: int = 0,
                     bucket=None):
@@ -418,12 +435,14 @@ class AotRegistry:
     def _role_fn(self, role: str):
         """The function compiled for ``role`` and its donated arguments.
         Each function carries its role's name, which XLA keeps as the
-        executable's module name (``jit_decode``, ``jit_prefill``, …)."""
+        executable's module name (``jit_decode``, ``jit_prefill``, …).
+        Decode takes the cache as its pool and its positions, and
+        donates the pool alone."""
         cfg, scfg, T = self.cfg, self.scfg, self._T
         if role == ROLE_DECODE:
-            def decode(p, c, t):
-                return T.decode_step(p, cfg, c, t)
-            return decode, ()
+            def decode(p, pool, pos, t):
+                return T.decode_step(p, cfg, {"runs": pool, "pos": pos}, t)
+            return decode, (1,)
         if role == ROLE_PREFILL:
             def prefill(p, b):
                 return T.prefill(p, cfg, b, max_len=scfg.max_len)
@@ -433,9 +452,10 @@ class AotRegistry:
         if role == ROLE_PURGE:
             return purge_rows, (0,)
         if role == ROLE_DECODE_PAGED:
-            def decode_paged(p, c, t, tbl):
-                return T.decode_step(p, cfg, c, t, table=tbl)
-            return decode_paged, ()
+            def decode_paged(p, pool, pos, t, tbl):
+                return T.decode_step(p, cfg, {"runs": pool, "pos": pos}, t,
+                                     table=tbl)
+            return decode_paged, (1,)
         if role == ROLE_PREFILL_EXT:
             def prefill_ext(p, b, arena, tbl):
                 return T.prefill_ext(p, cfg, b, arena, tbl)
@@ -493,8 +513,21 @@ class AotRegistry:
             exe = compiled
         else:
             self.stats["aot_cache_hits"] += 1
-        self._mem[memk] = exe
+        self._remember(role, variant, exe)
         return exe
+
+    def _remember(self, role: str, variant: Tuple, exe) -> None:
+        """Keep ``exe`` for (role, variant). For a decode executable, also
+        record what its memory analysis says of the donated cache:
+        ``decode_alias_bytes``, the output bytes that reuse an input's
+        buffer (the whole pool, when it is updated in place), and
+        ``decode_temp_bytes``, its scratch (where the backend has them)."""
+        self._mem[(role, variant)] = exe
+        if role in (ROLE_DECODE, ROLE_DECODE_PAGED):
+            ma = exe.memory_analysis()
+            if ma is not None:
+                self.stats["decode_alias_bytes"] = int(ma.alias_size_in_bytes)
+                self.stats["decode_temp_bytes"] = int(ma.temp_size_in_bytes)
 
     def _call(self, role: str, variant: Tuple, *args):
         exe = self._resolve(role, variant, args)
@@ -507,12 +540,14 @@ class AotRegistry:
             self.stats["aot_fallbacks"] += 1
             compiled = self._compile(role, args)
             self.stats["aot_compiles"] += 1
-            self._mem[(role, variant)] = compiled
+            self._remember(role, variant, compiled)
             return compiled(*args)
 
     # ---- role dispatch ---------------------------------------------------
     def decode(self, params, cache, tokens, *, level: int = 0):
-        return self._call(ROLE_DECODE, (level,), params, cache, tokens)
+        logits, new = self._call(ROLE_DECODE, (level,), params,
+                                 cache["runs"], cache["pos"], tokens)
+        return logits, _hand_over(cache, new)
 
     def prefill(self, params, batch, *, level: int = 0, bucket=None):
         if bucket is None:         # exact-length path (recurrent archs)
@@ -528,8 +563,9 @@ class AotRegistry:
         return self._call(ROLE_PURGE, (), pool, rows)
 
     def decode_paged(self, params, cache, tokens, table, *, level: int = 0):
-        return self._call(ROLE_DECODE_PAGED, (level,),
-                          params, cache, tokens, table)
+        logits, new = self._call(ROLE_DECODE_PAGED, (level,), params,
+                                 cache["runs"], cache["pos"], tokens, table)
+        return logits, _hand_over(cache, new)
 
     def prefill_ext(self, params, batch, arena, table, *, level: int = 0,
                     bucket=None):
@@ -601,10 +637,13 @@ class AotRegistry:
             cache_aval = self._cache_aval()
             tok_aval = jax.ShapeDtypeStruct((B, 1), i32)
             slots_aval = jax.ShapeDtypeStruct((B,), i32)
+            # the full-rank decode is loaded now, not left on disk: its
+            # memory analysis (decode_alias_bytes) is a boot fact
             if not paged:
                 for level, params in enumerate(ladder):
-                    self._ensure(ROLE_DECODE, (level,),
-                                 (params, cache_aval, tok_aval))
+                    (self._ensure if level else self._resolve)(
+                        ROLE_DECODE, (level,), (params, cache_aval["runs"],
+                                                cache_aval["pos"], tok_aval))
             if bucketed:
                 src_aval = None
                 for sb in self.prefill_buckets():
@@ -633,8 +672,10 @@ class AotRegistry:
             tbl_aval = jax.ShapeDtypeStruct((B, NB), i32)
             starts_aval = jax.ShapeDtypeStruct((B,), i32)
             for level, params in enumerate(ladder):
-                self._ensure(ROLE_DECODE_PAGED, (level,),
-                             (params, arena_aval, tok_aval, tbl_aval))
+                (self._ensure if level else self._resolve)(
+                    ROLE_DECODE_PAGED, (level,),
+                    (params, arena_aval["runs"], arena_aval["pos"], tok_aval,
+                     tbl_aval))
             pre_fn, _ = self._role_fn(ROLE_PREFILL)
             ext_fn, _ = self._role_fn(ROLE_PREFILL_EXT)
             seen_s = set()

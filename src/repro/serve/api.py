@@ -338,7 +338,10 @@ def load_engine(opts: ServeOptions, *, replica: int = 0,
         _echo(echo, f"AOT warm in {time.perf_counter() - t0:.2f}s: "
                     f"{s['aot_cache_hits']} cache hits, "
                     f"{s['aot_compiles']} compiles "
-                    f"(cache: {cb.exec.cache.dir})")
+                    f"(cache: {cb.exec.cache.dir})"
+                    + (f"; decode updates {s['decode_alias_bytes']} cache "
+                       f"bytes in place, {s['decode_temp_bytes']} temp bytes"
+                       if "decode_alias_bytes" in s else ""))
     return cb
 
 

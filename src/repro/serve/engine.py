@@ -180,7 +180,8 @@ class Engine:
             self.stats["decode_retraces"] += 1
             return T.decode_step(p, cfg, c, t)
 
-        self._decode = jax.jit(_decode_fn)
+        # decode consumes the cache: its pool is updated in place
+        self._decode = jax.jit(_decode_fn, donate_argnums=(1,))
         self._prefill_cache: Dict[int, object] = {}
         self.key = jax.random.PRNGKey(scfg.seed)
 

@@ -192,7 +192,8 @@ def test_compiled_executables_are_named_for_their_role(tmp_path, comp):
     tok = jax.ShapeDtypeStruct((SCFG.batch, 1), np.int32)
     batch = {"tokens": jax.ShapeDtypeStruct((SCFG.batch, 8), np.int32),
              "lengths": jax.ShapeDtypeStruct((SCFG.batch,), np.int32)}
-    entries = ((aotlib.ROLE_DECODE, (0,), (comp, cache, tok)),
+    entries = ((aotlib.ROLE_DECODE, (0,),
+                (comp, cache["runs"], cache["pos"], tok)),
                (aotlib.ROLE_PREFILL, (0, 8), (comp, batch)))
     for boot in range(2):
         reg = _registry(comp, tmp_path)
@@ -200,3 +201,69 @@ def test_compiled_executables_are_named_for_their_role(tmp_path, comp):
             head = reg._resolve(role, variant, args).as_text().split(",")[0]
             assert head == f"HloModule jit_{role}"
         assert reg.stats["aot_cache_hits"] == 2 * boot
+
+
+# ---------------------------------------------------------------------------
+# decode updates the donated KV pool in place
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def dense():
+    params, _ = T.init_model(CFG, jax.random.PRNGKey(0))
+    return params
+
+
+def _decode_memory(cb):
+    """(alias, temp) bytes of the batcher's full-rank decode executable
+    (the AOT registry's boot counters, or the traced registry's jitted
+    decode compiled for the batcher's own arguments), and the temp bytes
+    of the same step compiled without donation."""
+    table = cb._table_jnp() if cb.paged else None
+    plain = jax.jit(lambda p, c, t, tbl: T.decode_step(p, CFG, c, t,
+                                                       table=tbl))
+    base = plain.lower(cb.params, cb.cache, cb.tokens,
+                       table).compile().memory_analysis()
+    if cb.exec.kind == "aot":
+        cb.warm_executables()
+        return (cb.stats["decode_alias_bytes"], cb.stats["decode_temp_bytes"],
+                base.temp_size_in_bytes)
+    jitted = cb.exec._decode_paged if cb.paged else cb.exec._decode
+    args = (cb.params, cb.cache["runs"], cb.cache["pos"], cb.tokens)
+    ma = jitted.lower(*args, *([table] if cb.paged else [])).compile(
+        ).memory_analysis()
+    return (ma.alias_size_in_bytes, ma.temp_size_in_bytes,
+            base.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("registry", ["aot", "traced"])
+@pytest.mark.parametrize("layers", ["scan", "list", "paged"])
+def test_decode_updates_the_donated_pool_in_place(tmp_path, comp, dense,
+                                                  registry, layers):
+    """Every decode executable aliases the whole KV pool (scanned dense
+    layers, the compressed model's list of layers, the paged arena) and
+    adds less than a pool's worth of scratch to the undonated step, so it
+    keeps no copy of the pool; and a batcher step consumes the pool it was
+    given: the pre-step leaves are deleted, and the pre-step cache dict
+    points at the updated pool."""
+    params = comp if layers == "list" else dense
+    scfg = (dataclasses.replace(SCFG, kv_block=8) if layers == "paged"
+            else SCFG)
+    reg = (aotlib.AotRegistry(CFG, scfg, aotlib.live_fingerprint(params,
+                                                                 CFG),
+                              cache_dir=str(tmp_path))
+           if registry == "aot" else None)
+    cb = ContinuousBatcher(params, CFG, scfg, executables=reg)
+    pool = sum(leaf.nbytes for leaf in jax.tree.leaves(cb.cache["runs"]))
+    alias, temp, undonated_temp = _decode_memory(cb)
+    assert alias >= pool, (alias, pool)
+    assert temp < undonated_temp + pool, (temp, undonated_temp)  # no copy
+    for r in _workload(n=2, n_new=8):
+        cb.submit(r)
+    cb.step()                      # admits both requests, decodes once
+    for _ in range(3):             # decode only: nothing to admit or retire
+        old = cb.cache
+        leaves = jax.tree.leaves(old["runs"])
+        assert cb.step() == 2
+        assert all(leaf.is_deleted() for leaf in leaves)
+        assert old["runs"] is cb.cache["runs"]
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves(cb.cache))

@@ -140,3 +140,49 @@ def test_mesh_capture_compiles_for_v5e_2x2(topo, monkeypatch):
         text = fold.lower({t: acc(t) for t in tags},
                           {t: parts[t] for t in tags}).compile().as_text()
         assert "all-gather" in text and "all-reduce" in text
+
+
+def test_decode_step_updates_the_pool_in_place_for_v5e(one_chip, tmp_path):
+    """The served decode executable at SmolLM-360M size (32 layers, 32
+    slots, max_len 2048): the donated pool is aliased whole, no top-level
+    operation outputs a whole-pool or per-layer cache buffer other than
+    the in-place row writes, and the scratch stays below one pool (a
+    copy of the pool, as the pre-donation step made, needs more)."""
+    import re
+
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.serve import aot as aotlib
+    from repro.serve.engine import ServeConfig
+
+    cfg = get_config("smollm-360m")
+    scfg = ServeConfig(batch=32, max_len=2048)
+    fn, donate = aotlib.AotRegistry(cfg, scfg, "x", cache_dir=str(tmp_path)
+                                    )._role_fn(aotlib.ROLE_DECODE)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: T.init_model(cfg, jax.random.PRNGKey(0))[0]))
+    cache = on_chip(jax.eval_shape(lambda: T.init_cache(cfg, 32, 2048)))
+    tok = on_chip(jax.ShapeDtypeStruct((32, 1), I32))
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        params, cache["runs"], cache["pos"], tok).compile()
+    pool = sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves(cache["runs"]))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool
+    assert ma.temp_size_in_bytes < pool
+    body, fused = [], False
+    for line in compiled.as_text().splitlines():
+        if not line.startswith(" "):
+            fused = "fused" in line or "clone" in line
+        elif not fused:
+            body.append(line)
+    cache_ops = {m.group(1) for m in (
+        re.search(r"= bf16\[(?:32|1),32,2048,5,64\]\{[^}]*\} ([\w-]+)\(", ln)
+        for ln in body) if m}
+    assert cache_ops <= {"parameter", "get-tuple-element",
+                         "dynamic-update-slice", "bitcast", "tuple"}, \
+        cache_ops
