@@ -3,9 +3,9 @@
 These count the work the algorithm needs, not what an implementation
 happens to do: parameters are read once per step, each leaf at the
 narrower of its stored dtype and the compute dtype; the KV cache is read
-only over each slot's live positions; padding does no useful work. A
-faster implementation raises a share computed from these counts; it can
-not change the counts.
+only over each slot's live positions, and a windowed layer's only over
+its window; padding does no useful work. A faster implementation raises
+a share computed from these counts; it can not change the counts.
 
 Parameter trees are walked as nested dicts and lists of arrays (or
 ``jax.ShapeDtypeStruct``). A dict with a ``"w"`` leaf is a dense linear
@@ -16,7 +16,8 @@ embeddings are tied.
 """
 from __future__ import annotations
 
-from typing import Iterable, Iterator, Tuple
+import collections
+from typing import Iterable, Iterator, List, Tuple
 
 import numpy as np
 
@@ -86,18 +87,45 @@ def linear_flops_per_token(tree, tied_head: bool) -> int:
     return total
 
 
-def attention_flops(context: int, n_layers: int, n_heads: int,
-                    head_dim: int) -> int:
+def _by_window(windows: Iterable[int]) -> List[Tuple[int, int]]:
+    """(window, layers with it) per distinct window, in first-seen order."""
+    return list(collections.Counter(windows).items())
+
+
+def attention_flops(context: float, windows: Iterable[int], n_heads: int,
+                    head_dim: int) -> float:
     """FLOPs of scores and weighted values for one query token attending
-    over ``context`` positions, over all layers."""
-    return 4 * context * n_heads * head_dim * n_layers
+    over ``context`` positions, over the layers whose attention windows
+    are ``windows``: a layer reads at most its window (0: all of them)."""
+    total = 0
+    for w, n in _by_window(windows):
+        c = min(context, w) if w else context
+        total += 4 * c * n_heads * head_dim * n
+    return total
 
 
-def kv_bytes(live_lengths: Iterable[int], n_layers: int, n_kv_heads: int,
-             head_dim: int, cache_dtype) -> int:
-    """Bytes of K and V over the live positions of each slot."""
-    per_pos = 2 * n_layers * n_kv_heads * head_dim * _itemsize(cache_dtype)
-    return per_pos * int(sum(live_lengths))
+def prompt_attention_flops(prompt_len: int, windows: Iterable[int],
+                           n_heads: int, head_dim: int) -> float:
+    """FLOPs of scores and weighted values for every token of a prompt of
+    ``prompt_len`` attending over its own context (itself and the tokens
+    before it), each layer over at most its window."""
+    P = prompt_len
+    total = 0
+    for w, n in _by_window(windows):
+        pos = (P * (P + 1) / 2 if not w or P <= w
+               else w * (w + 1) / 2 + (P - w) * w)
+        total += 4 * pos * n_heads * head_dim * n
+    return total
+
+
+def kv_bytes(slots: int, context: float, windows: Iterable[int],
+             n_kv_heads: int, head_dim: int, cache_dtype) -> int:
+    """Bytes of K and V that ``slots`` slots at a mean live context of
+    ``context`` positions read, each layer over at most its window."""
+    per_pos = 2 * n_kv_heads * head_dim * _itemsize(cache_dtype)
+    return sum(per_pos * n * round(slots * (min(context, w) if w
+                                            else context))
+               for w, n in _by_window(windows))
 
 
 def roofline_seconds(flops: float, nbytes: float, peak_flops: float,

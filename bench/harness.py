@@ -7,8 +7,34 @@ Everything a cell is made of is found by name: the cell in
 traffic mix in ``bench/traffic/<traffic>.json``, each metric in
 ``bench/metrics/<metric>.py`` (a ``read(run)`` that returns a number, or
 ``None`` when there is nothing to read), and the plain reference in the
-file the configuration's ``reference`` names. Adding a cell, a mix or a metric
-adds files and entries; no file here changes.
+file the configuration's ``reference`` names. Adding a cell, a mix, a
+metric or a configuration of another architecture adds files and
+entries; no file here changes.
+
+A configuration file gives ``name``, ``arch`` (the program's registered
+architecture it overrides), ``reference``, ``model`` (the published
+config's keys), ``serving`` (``param_dtype``, ``compute_dtype``),
+``engine`` (``batch``, ``max_len``, ``kv_block``), ``compression`` (or
+null), ``weights`` (``rule`` "seed", or "fixed" with a ``weight_seed``)
+and ``correct`` (``sample`` and each limit). Its reference module is all
+the harness knows of the architecture (``bench/reference/llama.py`` is
+one):
+
+* ``program_config(model) -> dict``: the ``ModelConfig`` fields the
+  ``model`` block sets;
+* ``sizes(cfg) -> dict``: what the reference's functions take as sizes;
+* ``from_program(params, cfg) -> dict``: the program's parameter tree in
+  the reference's layout;
+* ``token_gaps(params, sizes, tokens, served, quant)`` and, for a
+  compressed configuration, ``factor_misfits(dense, served, sizes,
+  tokens, n)`` and ``linear_counts(ref_params, cfg) -> (served,
+  dense)``: what decides ``correct``; ``int8_quant``, the control;
+* ``work(cfg, params) -> dict``: what serving costs, for the per-layer
+  readers (``linear_flops_per_token``, ``decode_param_bytes(live)``,
+  per-layer ``windows``, ``n_heads``, ``n_kv_heads``, ``head_dim``,
+  ``vocab_size``, ``kv_dtype``);
+* ``TINY_MODEL``: the ``model`` block of its CPU-test copy
+  (``bench/tiny.py``).
 
 A run: check for the chip, build the engine (weights made from the seed
 on the device, or a D-Rank artifact compressed once per checkout and
@@ -101,35 +127,17 @@ def read_metric(root: str, name: str):
 # ---------------------------------------------------------------------------
 # the model
 # ---------------------------------------------------------------------------
-HF_TO_PROGRAM = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
-
-
-def model_config(config: Dict):
-    """The program's ``ModelConfig`` for a configuration file, registered
-    under the configuration's name."""
+def model_config(config: Dict, ref):
+    """The program's ``ModelConfig`` for a configuration file, as its
+    reference module ``ref`` maps the ``model`` block, registered under
+    the configuration's name."""
     from repro import configs
-    m = config["model"]
-    over = {HF_TO_PROGRAM[k]: v for k, v in m.items() if k in HF_TO_PROGRAM}
-    over["head_dim"] = m["hidden_size"] // m["num_attention_heads"]
+    over = ref.program_config(config["model"])
     over["dtype"] = config["serving"]["compute_dtype"]
     over["param_dtype"] = config["serving"]["param_dtype"]
     cfg = configs.get_config(config["arch"]).replace(**over)
     configs.register(config["name"], cfg)
     return cfg
-
-
-def ref_sizes(cfg) -> Dict:
-    return {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
-            "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
-            "norm_eps": cfg.norm_eps}
 
 
 def param_shapes(cfg):
@@ -227,24 +235,24 @@ def build_engine(root: str, config: Dict, cfg, seed: int):
     return cb
 
 
-def dense_reference_params(config: Dict, cfg, seed: int) -> Dict:
+def dense_reference_params(m: "Measured") -> Dict:
     """The dense weights, made again from the seed by the benchmark, in
     the reference's layout: those the program was given to serve, or to
     compress."""
     from bench import weights
-    params = weights.make(param_shapes(cfg), weight_seed(config, seed))
-    return weights.program_to_reference(params, cfg.n_layers)
+    params = weights.make(param_shapes(m.cfg), weight_seed(m.config, m.seed))
+    return m.ref.from_program(params, m.cfg)
 
 
-def reference_params(root: str, config: Dict, cfg, seed: int) -> Dict:
+def reference_params(m: "Measured") -> Dict:
     """The served parameters in the reference's layout, loaded afresh:
     the compressed artifact read from disk with numpy, or the dense
     weights made again from the seed."""
     from bench import weights
-    if config.get("compression"):
-        return weights.program_to_reference(
-            weights.load_artifact(artifact_dir(root, config)), cfg.n_layers)
-    return dense_reference_params(config, cfg, seed)
+    if m.config.get("compression"):
+        return m.ref.from_program(weights.load_artifact(
+            artifact_dir(m.root, m.config)), m.cfg)
+    return dense_reference_params(m)
 
 
 # ---------------------------------------------------------------------------
@@ -309,8 +317,7 @@ class Run:
     setup_s: float
     records: List[load.Record]
     batch: int
-    cfg: Any                        # the program's ModelConfig
-    served: Dict                    # counts of the served parameters
+    work: Dict                      # the reference module's work(cfg, params)
     peaks: Dict[str, float]
     t0: float = 0.0                 # traced window on the host clock
     t1: float = 0.0
@@ -357,13 +364,6 @@ class Run:
                 if i >= 0 and r.times[0] - ends[i] < 1.0:
                     out[i].append(r.prompt_len)
         return out
-
-
-def served_counts(params, cfg) -> Dict:
-    from bench import counts
-    return {"param_bytes": counts.param_bytes(params, cfg.dtype),
-            "linear_flops_per_token": counts.linear_flops_per_token(
-                params, cfg.tie_embeddings)}
 
 
 # ---------------------------------------------------------------------------
@@ -426,17 +426,11 @@ def misfits_of(ref, dense, served, sizes: Dict, r: load.Record,
                               P + n)
 
 
-def achieved_ratio(ref_params: Dict, cfg) -> float:
-    """1 - (parameters of the served linears) / (their dense count), the
-    served parameters counted from shapes, a shared basis once."""
-    from bench import counts
-    lin = [lp[k] for lp in ref_params["layers"]
-           for k in ("q", "k", "v", "o", "gate", "up", "down")]
-    dense = (cfg.n_layers * (cfg.d_model * (cfg.n_heads + 2 * cfg.n_kv_heads)
-                             * cfg.head_dim
-                             + cfg.n_heads * cfg.head_dim * cfg.d_model
-                             + 3 * cfg.d_model * cfg.d_ff))
-    return 1.0 - counts.param_count(lin) / dense
+def achieved_ratio(ref, ref_params: Dict, cfg) -> float:
+    """1 - (parameters of the served linears) / (their dense count), as
+    the reference module ``ref`` counts them."""
+    served, dense = ref.linear_counts(ref_params, cfg)
+    return 1.0 - served / dense
 
 
 # ---------------------------------------------------------------------------
@@ -489,6 +483,7 @@ class Measured:
     root: str
     seed: int
     config: Dict
+    ref: Any                        # the configuration's reference module
     cfg: Any
     run: Run
     picked: List[load.Record]       # sampled for the reference
@@ -529,7 +524,8 @@ def measure(argv: Optional[List[str]] = None, *, root: str = ROOT,
     compile_cache.enable()
     pk = peaks.peak(dev.device_kind) if require_tpu else {}
     config, mix = cell.config, cell.mix
-    cfg = model_config(config)
+    ref = load_module(root, config["reference"])
+    cfg = model_config(config, ref)
     max_len = config["engine"]["max_len"]
     compiles, on_compile = compile_counter()
 
@@ -585,7 +581,7 @@ def measure(argv: Optional[List[str]] = None, *, root: str = ROOT,
             f"clients_ended={drained}"
             + (f" generator_lateness_max_s={late:.6f}"
                if late is not None else ""))
-        served = served_counts(cb.params, cfg)
+        work = ref.work(cfg, cb.params)
     finally:
         door.close()
         jax.monitoring.unregister_event_duration_listener(on_compile)
@@ -595,8 +591,8 @@ def measure(argv: Optional[List[str]] = None, *, root: str = ROOT,
 
     records = clients.records
     runinfo = Run(seconds=args.seconds, w0=w0, w1=w1, setup_s=setup_s,
-                  records=records, batch=batch, cfg=cfg, served=served,
-                  peaks=pk, t0=t0, t1=t1)
+                  records=records, batch=batch, work=work, peaks=pk,
+                  t0=t0, t1=t1)
     breakdown = None
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
@@ -632,8 +628,8 @@ def measure(argv: Optional[List[str]] = None, *, root: str = ROOT,
     failed = sum(1 for r in in_window if r.status != "done")
     picked = sample(records, w1, int(config["correct"]["sample"]),
                     args.seed)
-    return Measured(root=root, seed=args.seed, config=config, cfg=cfg,
-                    run=runinfo, picked=picked, failed=failed,
+    return Measured(root=root, seed=args.seed, config=config, ref=ref,
+                    cfg=cfg, run=runinfo, picked=picked, failed=failed,
                     attempted=len(in_window), device=device,
                     breakdown=breakdown, metrics=cell.metrics)
 
@@ -647,14 +643,13 @@ def judge(m: Measured) -> Dict[str, Dict]:
     requests that failed in the window."""
     import jax
     t_ref = time.perf_counter()
-    config, cfg = m.config, m.cfg
+    config, cfg, ref = m.config, m.cfg, m.ref
     cc = config["correct"]
-    sizes = ref_sizes(cfg)
+    sizes = ref.sizes(cfg)
     max_len = config["engine"]["max_len"]
-    ref = load_module(m.root, config["reference"])
-    served = reference_params(m.root, config, cfg, m.seed)
+    served = reference_params(m)
     checks: Dict[str, Dict] = {}
-    ratio = (achieved_ratio(served, cfg) if config.get("compression")
+    ratio = (achieved_ratio(ref, served, cfg) if config.get("compression")
              else None)
     served = jax.device_put(served)
     with jax.default_matmul_precision("highest"):
@@ -664,8 +659,7 @@ def judge(m: Measured) -> Dict[str, Dict]:
         if config.get("compression"):
             fits = []
             if m.picked:
-                dense = jax.device_put(
-                    dense_reference_params(config, cfg, m.seed))
+                dense = jax.device_put(dense_reference_params(m))
                 fits = [v for row in misfits_of(ref, dense, served, sizes,
                                                 m.picked[0], max_len)
                         for v in row.values()]

@@ -2,13 +2,14 @@
 this chip, over the device time they took (%). Both come from the
 trace: each call whose first run starts in the traced window, its live
 slots from the program's ``decode_step`` span. Work is counted from
-shapes (bench/counts.py): per call, the served parameters once at the
-narrower of stored and compute dtype, K and V of each live slot over its
-live positions, the live slots' logits out; FLOPs of every linear and of
-attention over the live context. The live context of a slot is the mean
-over the tokens clients received in the traced window. The least time
-is the larger of FLOPs over peak and bytes over HBM bandwidth; on this
-path it is the bytes."""
+shapes by the configuration's reference module (``run.work``) with
+bench/counts.py: per call, the parameter bytes a step over its live
+slots reads, K and V of each live slot over its live positions (a
+windowed layer's over at most its window), the live slots' logits out;
+FLOPs of every linear and of attention over the live context. The live
+context of a slot is the mean over the tokens clients received in the
+traced window. The least time is the larger of FLOPs over peak and
+bytes over HBM bandwidth; on this path it is the bytes."""
 from bench import counts
 
 
@@ -17,17 +18,19 @@ def read(run):
     ctx = run.decode_context()
     if not cs or ctx is None:
         return None
-    cfg = run.cfg
+    w = run.work
     least = spent = 0.0
     for c in cs:
         live = int(c.span.stats["live"])
-        nbytes = (run.served["param_bytes"]
-                  + counts.kv_bytes([round(live * ctx)], cfg.n_layers,
-                                    cfg.n_kv_heads, cfg.head_dim, cfg.dtype)
-                  + live * cfg.vocab_size * 2)
-        flops = live * (run.served["linear_flops_per_token"]
-                        + counts.attention_flops(ctx, cfg.n_layers,
-                                                 cfg.n_heads, cfg.head_dim))
+        nbytes = (w["decode_param_bytes"](live)
+                  + counts.kv_bytes(live, ctx, w["windows"],
+                                    w["n_kv_heads"], w["head_dim"],
+                                    w["kv_dtype"])
+                  + live * w["vocab_size"] * 2)
+        flops = live * (w["linear_flops_per_token"]
+                        + counts.attention_flops(ctx, w["windows"],
+                                                 w["n_heads"],
+                                                 w["head_dim"]))
         t, _ = counts.roofline_seconds(flops, nbytes,
                                        run.peaks["bf16_flops_per_s"],
                                        run.peaks["hbm_bytes_per_s"])

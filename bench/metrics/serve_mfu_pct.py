@@ -6,25 +6,24 @@ window. Useful: per decode call, one token for each live slot (the
 program's ``decode_step`` span), attention over the mean live context of
 the tokens decoded in the window; per prefill call, every prompt token
 of the requests it admitted, attention over each token's own context.
-All at the served shapes (factor shapes for a compressed model);
-padding is not counted."""
+A windowed layer attends over at most its window. All at the served
+shapes (factor shapes for a compressed model), as the configuration's
+reference module counts them (``run.work``); padding is not counted."""
 from bench import counts
 
 
 def read(run):
     if run.trace is None or not run.calls:
         return None
-    cfg = run.cfg
-    lin = run.served["linear_flops_per_token"]
-
-    def attn(c):
-        return counts.attention_flops(c, cfg.n_layers, cfg.n_heads,
-                                      cfg.head_dim)
+    w = run.work
+    lin = w["linear_flops_per_token"]
+    shape = (w["windows"], w["n_heads"], w["head_dim"])
     ctx = run.decode_context()
     prefills = [c for c in run.calls if c.role == "prefill"]
     prompts = run.admitted([run.host(c.span.end) for c in prefills])
     useful = dict(zip(map(id, prefills),
-                      (sum(P * lin + attn(P * (P + 1) / 2) for P in ps)
+                      (sum(P * lin + counts.prompt_attention_flops(P, *shape)
+                           for P in ps)
                        for ps in prompts)))
     flops = 0.0
     for c in run.calls:
@@ -33,7 +32,8 @@ def read(run):
         if c.role == "decode_step":
             if ctx is None:
                 return None
-            f = int(c.span.stats["live"]) * (lin + attn(ctx))
+            f = int(c.span.stats["live"]) * (
+                lin + counts.attention_flops(ctx, *shape))
         else:
             f = useful[id(c)]
         flops += f * c.in_window_s / c.device_s

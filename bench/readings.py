@@ -79,19 +79,17 @@ def main(argv=None) -> int:
         row = {"seed": int(seed), "attempted": m.attempted,
                "failed": m.failed,
                **{k: c["value"] for k, c in checks.items()}}
-        config, cfg = m.config, m.cfg
-        sizes = harness.ref_sizes(cfg)
+        config, ref = m.config, m.ref
+        sizes = ref.sizes(m.cfg)
         max_len = config["engine"]["max_len"]
-        ref = harness.load_module(m.root, config["reference"])
-        served = harness.reference_params(m.root, config, cfg, m.seed)
+        served = harness.reference_params(m)
         with jax.default_matmul_precision("highest"):
             if a.int8:
                 cg = harness.gaps_of(ref, jax.device_put(served), sizes,
                                      m.picked, max_len, ref.int8_quant)
                 row["int8_token_gap"] = max(cg)
             if config.get("compression"):
-                dense = jax.device_put(
-                    harness.dense_reference_params(config, cfg, m.seed))
+                dense = jax.device_put(harness.dense_reference_params(m))
                 for fault in FAULTS:
                     bad = jax.device_put(planted(served, fault, m.seed))
                     fits = harness.misfits_of(ref, dense, bad, sizes,

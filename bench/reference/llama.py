@@ -1,7 +1,7 @@
 """Plain float32 reference of a Llama-architecture decoder (SmolLM-360M's
 family): token embedding, ``n_layers`` pre-norm blocks of grouped-query
 attention with rotary positions and a SwiGLU MLP, final RMSNorm, output
-head tied to the embedding.
+head tied to the embedding (or a linear of its own where it is not).
 
 It imports nothing of the program under test. It has no cache, no
 batching, no padding and no kernels: one sequence, every position, every
@@ -16,18 +16,24 @@ GPT-NeoX layout Llama checkpoints use in Hugging Face transformers).
 ``sizes`` keys: ``n_layers d_model n_heads n_kv_heads head_dim d_ff
 vocab_size rope_theta norm_eps``.
 
-Params layout::
+Params layout (``head`` only where the output head is not tied)::
 
-    {"embed": (V, D), "final_norm": (D,),
+    {"embed": (V, D), "final_norm": (D,), "head": linear,
      "layers": [{"ln1": (D,), "ln2": (D,), "q", "k", "v", "o",
                  "gate", "up", "down": linear}, ...]}
+
+Besides the reference itself, this module is what the harness knows of
+the architecture (``bench/harness.py``): ``program_config``, ``sizes``,
+``from_program``, ``linear_counts``, ``work`` and ``TINY_MODEL``.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from bench import counts
 
 HIGHEST = jax.lax.Precision.HIGHEST
 Quant = Optional[Callable[[jax.Array], jax.Array]]
@@ -107,6 +113,8 @@ def logits(params: Dict, sizes: Dict, tokens: jax.Array,
     for lp in params["layers"]:
         x, _ = block(lp, sizes, x, quant)
     x = _rms(x, params["final_norm"], sizes["norm_eps"])
+    if "head" in params:
+        return _linear(params["head"], x, quant)
     return jnp.matmul(_q(x, quant), _q(params["embed"], quant).T,
                       precision=HIGHEST)
 
@@ -175,3 +183,102 @@ def factor_misfits(dense: Dict, served: Dict, sizes: Dict,
             row[k] = float(fit(ins[k] * keep, w.astype(jnp.float32), B, C))
         out.append(row)
     return out
+
+
+# ---------------------------------------------------------------------------
+# what the harness knows of the architecture
+# ---------------------------------------------------------------------------
+# the configuration file's ``model`` block (Hugging Face names) to the
+# fields of the program's ``ModelConfig``
+HF_TO_PROGRAM = {
+    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+
+# the ``model`` block of a configuration in the benchmark's CPU tests
+# (``bench/tiny.py``): two narrow layers, a short context
+TINY_MODEL = {"num_hidden_layers": 2, "hidden_size": 64,
+              "num_attention_heads": 4, "num_key_value_heads": 2,
+              "intermediate_size": 128, "vocab_size": 256,
+              "max_position_embeddings": 64}
+
+
+def program_config(model: Dict) -> Dict:
+    """The ``ModelConfig`` fields that the configuration's ``model`` block
+    sets; ``head_dim`` is the block's own, or hidden / heads."""
+    over = {HF_TO_PROGRAM[k]: v for k, v in model.items()
+            if k in HF_TO_PROGRAM}
+    over["head_dim"] = model.get(
+        "head_dim", model["hidden_size"] // model["num_attention_heads"])
+    return over
+
+
+def sizes(cfg) -> Dict:
+    """The reference's ``sizes`` from the program's ``ModelConfig``."""
+    return {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
+            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.head_dim, "d_ff": cfg.d_ff,
+            "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
+            "norm_eps": cfg.norm_eps}
+
+
+PROGRAM_NAMES = {"q": ("attn", "wq"), "k": ("attn", "wk"),
+                 "v": ("attn", "wv"), "o": ("attn", "wo"),
+                 "gate": ("mlp", "w_gate"), "up": ("mlp", "w_up"),
+                 "down": ("mlp", "w_down")}
+
+
+def _plain_linear(node: Dict) -> Dict:
+    return ({"B": node["B"], "C": node["C"]} if "B" in node
+            else {"w": node["w"]})
+
+
+def from_program(params: Dict, cfg) -> Dict:
+    """The program's parameter tree (arrays or numpy) in the reference's
+    layout: the layers of each ``decoder/run{r}`` in ``cfg.layer_runs()``
+    order, a run stacked along its leading axis or a list of layers."""
+    layers = []
+    for r, (_, n) in enumerate(cfg.layer_runs()):
+        run = params["decoder"][f"run{r}"]
+        for i in range(n):
+            lp = run[i] if isinstance(run, list) else jax.tree.map(
+                lambda a: a[i], run)
+            layer = {"ln1": lp["ln1"]["scale"], "ln2": lp["ln2"]["scale"]}
+            for short, (blk, lin) in PROGRAM_NAMES.items():
+                layer[short] = _plain_linear(lp[blk][lin])
+            layers.append(layer)
+    out = {"embed": params["embed"],
+           "final_norm": params["final_norm"]["scale"], "layers": layers}
+    if "lm_head" in params:
+        out["head"] = _plain_linear(params["lm_head"])
+    return out
+
+
+def linear_counts(ref_params: Dict, cfg) -> Tuple[int, int]:
+    """Parameters of the layers' linears as served (counted from shapes,
+    a shared basis once) and as the dense model has them."""
+    lin = [lp[k] for lp in ref_params["layers"] for k in LINEARS]
+    dense = (cfg.n_layers * (cfg.d_model * (cfg.n_heads + 2 * cfg.n_kv_heads)
+                             * cfg.head_dim
+                             + cfg.n_heads * cfg.head_dim * cfg.d_model
+                             + 3 * cfg.d_model * cfg.d_ff))
+    return counts.param_count(lin), dense
+
+
+def work(cfg, params: Any) -> Dict:
+    """What serving the program's parameter tree ``params`` costs, for the
+    per-layer readers: every linear's FLOPs per token (the tied head
+    included), the parameter bytes a decode step over ``live`` slots reads
+    (all of them, whatever ``live``), each layer's attention window (0:
+    the whole context) and the shapes attention and the logits run at."""
+    nbytes = counts.param_bytes(params, cfg.dtype)
+    return {"linear_flops_per_token": counts.linear_flops_per_token(
+                params, cfg.tie_embeddings),
+            "decode_param_bytes": lambda live: nbytes,
+            "windows": [0] * cfg.n_layers,
+            "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
+            "head_dim": cfg.head_dim, "vocab_size": cfg.vocab_size,
+            "kv_dtype": cfg.dtype}

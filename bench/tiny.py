@@ -1,7 +1,8 @@
 """A copy of the benchmark at a size a CPU test can run: the same files,
-with each configuration cut to two narrow layers and short contexts, and
-mixes short enough to finish in a second. For the benchmark's own tests;
-no cell of ``BENCHMARK.json`` runs at these sizes."""
+with each configuration's ``model`` block cut to its reference module's
+``TINY_MODEL`` (a few narrow layers, a short context), and mixes short
+enough to finish in a second. For the benchmark's own tests; no cell of
+``BENCHMARK.json`` runs at these sizes."""
 from __future__ import annotations
 
 import json
@@ -9,11 +10,8 @@ import os
 import shutil
 from typing import Dict
 
-from bench.harness import ROOT
+from bench.harness import ROOT, load_module
 
-MODEL = {"num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
-         "num_key_value_heads": 2, "intermediate_size": 128,
-         "vocab_size": 256, "max_position_embeddings": 64}
 ENGINE = {"batch": 4, "max_len": 64}
 COMPRESSION = {"calib_samples": 8, "calib_seq": 32}
 # at these sizes, over 16 runs on the CPU (8 seeds x both configurations,
@@ -69,7 +67,9 @@ def make_root(dst: str, src: str = ROOT) -> str:
         p = os.path.join(cdir, f)
         with open(p) as fh:
             c = json.load(fh)
-        _merge(c, {"model": MODEL, "engine": ENGINE, "correct": CORRECT})
+        tiny_model = load_module(dst, c["reference"]).TINY_MODEL
+        _merge(c, {"model": tiny_model, "engine": ENGINE,
+                   "correct": CORRECT})
         if c.get("compression"):
             _merge(c["compression"], COMPRESSION)
         with open(p, "w") as fh:

@@ -2,10 +2,12 @@
 
 The program gives only the layout (``jax.eval_shape`` of its own init);
 the values come from here, so the plain reference never takes weights
-the program made. Every linear is drawn N(0, 1/d_in), so each layer's
-output is of the order of the residual stream and no layer is a near
-no-op; the embedding is N(0, 1/d_model), so tied logits are O(1); norm
-scales are ones.
+the program made. One rule for every architecture, by a leaf's name and
+shape alone: a norm ``scale`` is ones; the ``embed`` is N(0, 1/d_model),
+so tied logits are O(1); any other floating leaf of rank 2 or more is a
+matrix (or a stack of them: layers, experts) read along its
+next-to-last axis, and is drawn N(0, 1/d_in), so each layer's output is
+of the order of the residual stream and no layer is a near no-op.
 """
 from __future__ import annotations
 
@@ -33,7 +35,8 @@ def make(shapes: Any, seed: int) -> Any:
             kinds.append(("ones", None))
         elif names[-1] == "embed":
             kinds.append(("normal", sds.shape[-1] ** -0.5))
-        elif names[-1] in ("w", "B", "C"):
+        elif (len(sds.shape) >= 2
+              and jnp.issubdtype(sds.dtype, jnp.floating)):
             kinds.append(("normal", sds.shape[-2] ** -0.5))
         else:
             raise ValueError(f"no rule for parameter {'/'.join(names)}")
@@ -87,24 +90,3 @@ def load_artifact(path: str) -> Any:
             return arrays[key]
 
         return build(structure)
-
-
-def program_to_reference(params: Dict, n_layers: int) -> Dict:
-    """The program's Llama layout (stacked ``decoder/run0`` or a list of
-    per-layer trees) to the reference's plain layout."""
-    run = params["decoder"]["run0"]
-    names = {"q": ("attn", "wq"), "k": ("attn", "wk"), "v": ("attn", "wv"),
-             "o": ("attn", "wo"), "gate": ("mlp", "w_gate"),
-             "up": ("mlp", "w_up"), "down": ("mlp", "w_down")}
-    layers = []
-    for i in range(n_layers):
-        lp = run[i] if isinstance(run, list) else jax.tree.map(
-            lambda a: a[i], run)
-        layer = {"ln1": lp["ln1"]["scale"], "ln2": lp["ln2"]["scale"]}
-        for short, (blk, lin) in names.items():
-            node = lp[blk][lin]
-            layer[short] = ({"B": node["B"], "C": node["C"]} if "B" in node
-                            else {"w": node["w"]})
-        layers.append(layer)
-    return {"embed": params["embed"],
-            "final_norm": params["final_norm"]["scale"], "layers": layers}

@@ -64,13 +64,12 @@ def test_sound_compressed_run_is_correct(run):
     assert res["checks"]["ratio_error"]["value"] <= 0.01
 
 
-def test_int8_control_in_the_programs_place_is_not_correct(root, measure):
+def test_int8_control_in_the_programs_place_is_not_correct(measure):
     m = measure("smollm-360m-dense.batch")
-    ref = harness.load_module(root, m.config["reference"])
-    served = jax.device_put(harness.reference_params(root, m.config, m.cfg,
-                                                     m.seed))
+    ref = m.ref
+    served = jax.device_put(harness.reference_params(m))
     with jax.default_matmul_precision("highest"):
-        gaps = harness.gaps_of(ref, served, harness.ref_sizes(m.cfg),
+        gaps = harness.gaps_of(ref, served, ref.sizes(m.cfg),
                                m.picked, m.config["engine"]["max_len"],
                                ref.int8_quant)
     assert max(gaps) > m.config["correct"]["token_gap"], gaps
@@ -132,12 +131,12 @@ def test_broken_compression_is_not_correct(run, monkeypatch, tmp_path,
 
 
 @pytest.mark.parametrize("fault", readings.FAULTS)
-def test_planted_factor_faults_read_above_the_limit(root, measure, fault):
+def test_planted_factor_faults_read_above_the_limit(measure, fault):
     m = measure("smollm-360m-drank20.batch")
-    ref = harness.load_module(root, m.config["reference"])
-    sizes, max_len = harness.ref_sizes(m.cfg), m.config["engine"]["max_len"]
-    served = harness.reference_params(root, m.config, m.cfg, m.seed)
-    dense = harness.dense_reference_params(m.config, m.cfg, m.seed)
+    ref = m.ref
+    sizes, max_len = ref.sizes(m.cfg), m.config["engine"]["max_len"]
+    served = harness.reference_params(m)
+    dense = harness.dense_reference_params(m)
     with jax.default_matmul_precision("highest"):
         fits = harness.misfits_of(ref, dense, readings.planted(
             served, fault, m.seed), sizes, m.picked[0], max_len)

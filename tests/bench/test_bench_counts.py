@@ -71,14 +71,35 @@ def test_factorized_tree_flops_and_bytes_share_the_basis_once():
 
 def test_kv_bytes_over_live_lengths():
     # SmolLM-360M: 32 layers x K and V x 5 heads x 64 x 2 bytes = 40 KiB
-    # per position; two slots holding 100 and 28 positions
-    assert counts.kv_bytes([100, 28], 32, 5, 64, "bfloat16") == \
+    # per position; two slots at a mean of 64 live positions
+    full = [0] * 32
+    assert counts.kv_bytes(2, 64, full, 5, 64, "bfloat16") == \
         128 * 32 * 2 * 5 * 64 * 2
-    assert counts.kv_bytes([], 32, 5, 64, "bfloat16") == 0
+    assert counts.kv_bytes(0, 64, full, 5, 64, "bfloat16") == 0
+    # a mean that is no whole number: the slots' positions, rounded
+    assert counts.kv_bytes(3, 10.5, full, 5, 64, "bfloat16") == \
+        32 * 32 * 2 * 5 * 64 * 2
+    # three window-16 layers to one full one: the window layers read 16
+    # positions of each slot, the full one all 64
+    mixed = [16, 16, 16, 0] * 2
+    assert counts.kv_bytes(2, 64, mixed, 4, 32, "bfloat16") == \
+        2 * 4 * 32 * 2 * (6 * 2 * 16 + 2 * 2 * 64)
+    # a context inside the window is read whole
+    assert counts.kv_bytes(2, 10, mixed, 4, 32, "bfloat16") == \
+        counts.kv_bytes(2, 10, [0] * 8, 4, 32, "bfloat16")
 
 
 def test_attention_flops_and_roofline_bound():
-    assert counts.attention_flops(10, 2, 3, 4) == 4 * 10 * 3 * 4 * 2
+    assert counts.attention_flops(10, [0, 0], 3, 4) == 4 * 10 * 3 * 4 * 2
+    # one layer windowed at 4: it reads 4 positions, the other 10
+    assert counts.attention_flops(10, [4, 0], 3, 4) == 4 * (4 + 10) * 3 * 4
+    # a prompt of 6: token i reads i positions (1 + ... + 6 = 21), or at
+    # most 4 under the window (1 + 2 + 3 + 4 + 4 + 4 = 18)
+    assert counts.prompt_attention_flops(6, [0, 0], 3, 4) == \
+        4 * 21 * 3 * 4 * 2
+    assert counts.prompt_attention_flops(6, [4, 0], 3, 4) == \
+        4 * (18 + 21) * 3 * 4
+    assert counts.prompt_attention_flops(3, [4], 3, 4) == 4 * 6 * 3 * 4
     t, bound = counts.roofline_seconds(1e12, 1e9, 1e14, 1e12)
     assert (t, bound) == (pytest.approx(1e-2), "compute")
     t, bound = counts.roofline_seconds(1e9, 1e9, 1e14, 1e12)

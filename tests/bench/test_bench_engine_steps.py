@@ -31,8 +31,8 @@ def step(i, t0, t1, waits=(), decode=True):
 
 def run(spans, t0=10.0, t1=11.0):
     return harness.Run(seconds=51.0, w0=0.0, w1=51.0, setup_s=1.0,
-                       records=[], batch=32, cfg=None, served={},
-                       peaks={}, t0=t0, t1=t1, spans=spans)
+                       records=[], batch=32, work={}, peaks={},
+                       t0=t0, t1=t1, spans=spans)
 
 
 def read(name, r):

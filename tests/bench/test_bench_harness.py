@@ -95,6 +95,9 @@ def add_cell(src):
     bench["workloads"].append({"name": "newmodel.newmix",
                                "config": "newmodel", "traffic": "newmix",
                                "chips": 1, "why": "added by a test"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "itl_p95_ms":
+            m["workloads"].append("newmodel.newmix")
     bench["per_layer"].append({
         "name": "new_metric", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "scheduler",
